@@ -5,8 +5,9 @@ Runs deep-chain TC (the O(rounds)-vs-O(phases) host-sync scenario), a wide
 random-graph TC (few rounds, big per-round joins — the scenario where
 sharding the sort/merge work pays off), and LUBM-L through the sharded
 shard_map executor at ndev in {1, 2, 4, 8} (smoke: {1, 2}).  Each shard
-count runs in a subprocess (``xla_force_host_platform_device_count`` is
-locked at first jax init, so the parent process can't revisit it), warms
+count runs in a subprocess (one process per chip; on the CPU platform the
+shards are ``xla_force_host_platform_device_count`` host devices, which is
+locked at first jax init), warms
 until the capacity planner is stable (no cap in ``plan._CAP_MEMO`` moved on
 the last run — the while_loop fixpoint doubles tails geometrically, so two
 fixed warm passes are not enough), then times a steady-state run.
@@ -39,14 +40,26 @@ _SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 _SCRIPT = textwrap.dedent("""
     import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(ndev)d"
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        # the caller chose the CPU: simulate the shards as host devices
+        os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
+                                   "%(ndev)d")
     import sys, json, time
     sys.path.insert(0, %(src)r)
+    import jax
+    from repro import compile_cache
+    compile_cache.enable()
     from repro.data.kb_sources import (TC, LUBM_L, lubm_facts,
                                        tc_chain_facts, tc_random_facts)
     from repro.engine import ops, plan
+    from repro.engine.distributed import materialize_distributed
     from repro.engine.materialize import EngineKB, materialize
+    from repro.launch.mesh import make_data_mesh
+
+    if len(jax.devices()) < %(ndev)d:
+        print("RESULT []")      # fewer real devices than this shard count
+        sys.exit(0)
+    mesh = make_data_mesh(%(ndev)d)
 
     smoke = %(smoke)r
     scens = [
@@ -84,7 +97,8 @@ _SCRIPT = textwrap.dedent("""
                  "derived": st_f.derived, "rounds": st_f.rounds,
                  "fused_pulls": ops.HOST_SYNC_STATS.fused_pulls}
         t_d, st, kb = steady(
-            P, B, lambda kb: materialize(kb, mode="tg", backend="dist"))
+            P, B, lambda kb: materialize_distributed(kb, mode="tg",
+                                                     mesh=mesh))
         s = ops.HOST_SYNC_STATS
         out.append({
             "name": name, "seconds": t_d, "ndev": st.extra["ndev"],

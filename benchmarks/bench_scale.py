@@ -44,10 +44,11 @@ _SCRIPT = textwrap.dedent("""
     os.environ["REPRO_FUSED"] = "1"
     if %(dtype)r == "int64":
         os.environ["JAX_ENABLE_X64"] = "1"
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import sys, json, time, resource
     sys.path.insert(0, %(src)r)
     import jax
+    from repro import compile_cache
+    compile_cache.enable()
     import numpy as np
     from repro.data.kb_sources import TC, tc_wide_chunks, tc_wide_total
     from repro.engine import ops, plan

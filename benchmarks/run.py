@@ -14,6 +14,11 @@ two-phase rows are additionally written to ``BENCH_tc.json`` at the repo
 root — the stable per-commit trajectory of the transitive-closure
 benchmark: trigger counts, rounds, wall time, and host-sync counts for both
 executors.
+
+One process per chip: this script never touches JAX.  Each table runs in a
+child process of its own (``--child``), which hands its rows back on its
+last stdout line, so a table whose own children need the accelerator
+(``scale``, ``dist``) never finds it held by an earlier table.
 """
 from __future__ import annotations
 
@@ -21,6 +26,7 @@ import argparse
 import json
 import os
 import platform
+import subprocess
 import sys
 
 from benchmarks import (bench_chasebench, bench_datalog, bench_delta,
@@ -44,6 +50,41 @@ TABLES = {
 }
 
 
+_ROWS = "ROWS "
+
+
+def _child(name: str, smoke: bool, huge: bool) -> None:
+    """Run one table in this process; its rows go out as the last line."""
+    from repro import compile_cache
+    compile_cache.enable()
+    if name == "scale":
+        TABLES[name](smoke=smoke, huge=huge)
+    else:
+        TABLES[name](smoke=smoke)
+    print(_ROWS + json.dumps(common.RESULTS), flush=True)
+
+
+def _run_table(name: str, smoke: bool, huge: bool) -> list:
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cmd = [sys.executable, "-m", "benchmarks.run", "--child", name]
+    cmd += ["--smoke"] * smoke + ["--huge"] * huge
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src"), root]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    proc = subprocess.run(cmd, cwd=root, env=env, stdout=subprocess.PIPE,
+                          text=True)
+    lines = proc.stdout.splitlines()
+    rows = [ln for ln in lines if ln.startswith(_ROWS)]
+    for ln in lines:
+        if not ln.startswith(_ROWS):
+            print(ln, flush=True)
+    if proc.returncode != 0 or not rows:
+        raise SystemExit(f"[bench] table {name!r} failed "
+                         f"(exit {proc.returncode})")
+    return json.loads(rows[-1][len(_ROWS):])
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("tables", nargs="*", choices=[[], *TABLES],
@@ -55,16 +96,18 @@ def main() -> None:
                          "with --smoke, none otherwise)")
     ap.add_argument("--huge", action="store_true",
                     help="extend the scale sweep to 10^8 facts")
+    ap.add_argument("--child", choices=list(TABLES), default=None,
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
+    if args.child:
+        _child(args.child, args.smoke, args.huge)
+        return
 
     which = args.tables or list(TABLES)
     common.reset_results()
-    print("name,us_per_call,derived,extra...")
+    print("name,us_per_call,derived,extra...", flush=True)
     for name in which:
-        if name == "scale":
-            TABLES[name](smoke=args.smoke, huge=args.huge)
-        else:
-            TABLES[name](smoke=args.smoke)
+        common.RESULTS.extend(_run_table(name, args.smoke, args.huge))
 
     def write_payload(path, rows, **extra):
         payload = {

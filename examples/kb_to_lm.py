@@ -15,7 +15,7 @@ from repro.configs.base import ModelConfig
 from repro.data.kb_sources import LUBM_L, lubm_facts
 from repro.data.pipeline import KBLinearizer
 from repro.engine.materialize import EngineKB, materialize
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.models.layers import MeshCtx
 from repro.train.train_loop import train
@@ -53,7 +53,7 @@ def main():
     cfg = lm_100m(data.vocab_size).with_(num_layers=args.layers)
     n = cfg.param_counts()["total"]
     print(f"[model] {n/1e6:.1f}M params")
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     mcx = MeshCtx(mesh=mesh, dp=("data",), tp="model")
     mdl = M.build(cfg, mcx)
     ckpt = args.ckpt or os.path.join(tempfile.gettempdir(), "kb_lm_ckpt")

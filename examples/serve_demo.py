@@ -13,14 +13,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_smoke_config
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.models.layers import MeshCtx
 
 
 def main():
     cfg = get_smoke_config("stablelm_12b").with_(dtype="float32")
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     mcx = MeshCtx(mesh=mesh, dp=("data",), tp="model")
     mdl = M.build(cfg, mcx)
     params = mdl.init_params(jax.random.PRNGKey(0))

@@ -176,7 +176,14 @@ class Dictionary:
                                       return_inverse=True)
                 ids = self._intern_ints_unique(uniq)
                 return ids[inv].reshape(n, ar).astype(self.id_dtype)
-        uniq, inv = np.unique(flat, return_inverse=True)
+        try:
+            uniq, inv = np.unique(flat, return_inverse=True)
+        except TypeError:
+            # unorderable mix (e.g. str and int terms): nothing to sort by,
+            # so intern term by term; each value still lands in its own store
+            ids = np.fromiter((self.encode(t) for t in flat), np.int64,
+                              flat.size)
+            return ids.reshape(n, ar).astype(self.id_dtype)
         terms = uniq.tolist()
         is_int = [isinstance(t, (int, np.integer)) for t in terms]
         if all(is_int):

@@ -76,7 +76,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.engine import ops, recovery
 from repro.engine.plan import (_absorb_traced, _cached_program, _Caps,
                                _exec_rule_traced, _linear_tail,
@@ -147,8 +146,8 @@ def _route_to_buckets(rows, target, ndev, bucket_cap, sort_cols=None):
     if sort_cols is None:
         order = jnp.argsort(target)
     else:                 # lexsort: LAST key is primary -> target, then cols
-        order = jnp.lexsort(tuple(rows[:, c] for c in reversed(sort_cols))
-                            + (target,))
+        order = jnp.lexsort(tuple(ops._wide(rows[:, c])
+                                  for c in reversed(sort_cols)) + (target,))
     t_sorted = target[order]
     rows_sorted = rows[order]
     pos = jnp.arange(cap) - jnp.searchsorted(t_sorted, t_sorted, side="left")
@@ -156,12 +155,13 @@ def _route_to_buckets(rows, target, ndev, bucket_cap, sort_cols=None):
                      ndev * bucket_cap)
     overflow = jnp.logical_and(t_sorted < ndev, pos >= bucket_cap)
     slot = jnp.where(overflow, ndev * bucket_cap, slot)
-    buckets = jnp.full((ndev * bucket_cap + 1, ar), pad_of(rows), rows.dtype)
+    wide = ops._wide(rows_sorted)
+    buckets = jnp.full((ndev * bucket_cap + 1, ar), pad_of(rows), wide.dtype)
     buckets = buckets.at[slot].set(jnp.where((t_sorted < ndev)[:, None],
-                                             rows_sorted, pad_of(rows)),
+                                             wide, pad_of(rows)),
                                    mode="drop")
-    return (buckets[:ndev * bucket_cap].reshape(ndev, bucket_cap, ar),
-            jnp.sum(overflow))
+    return (buckets[:ndev * bucket_cap].reshape(ndev, bucket_cap, ar)
+            .astype(rows.dtype), jnp.sum(overflow))
 
 
 def _exchange(rows, target, ndev, axis, bucket_cap, sort_cols=None):
@@ -191,9 +191,10 @@ def _merge_runs(blk, ndev, perm):
     output slot is its index within its run plus one ``searchsorted`` count
     against every other run (ties broken by source-run index, so slots are
     unique), landed with a single scatter.  That is ndev*(ndev-1) binary
-    searches over packed keys instead of an O(n log n) re-sort of the whole
-    block; at ndev=1 the block is already fully sorted and nothing runs at
-    all.  Past ``_MERGE_MAX_WAYS`` runs (or rows too wide to pack) the
+    searches over scalar keys (``ops.scalar_key``) instead of an O(n log n)
+    re-sort of the whole block; at ndev=1 the block is already fully sorted
+    and nothing runs at all.  Past ``_MERGE_MAX_WAYS`` runs (or rows with
+    no scalar key) the
     quadratic probe count loses to XLA's sort, so it falls back to one full
     lexsort — same contract, no pre-sorted-run benefit."""
     n, ar = blk.shape
@@ -202,36 +203,33 @@ def _merge_runs(blk, ndev, perm):
         return blk
     cap = n // ndev
     rot = blk if identity else blk[:, list(perm)]
-    if ndev > _MERGE_MAX_WAYS or ar > 2 or (
-            ar == 2 and not ops._pack_ok(blk.dtype)):
+    runs = [rot[i * cap:(i + 1) * cap] for i in range(ndev)]
+    keys = [ops.scalar_key(r) for r in runs]
+    if ndev > _MERGE_MAX_WAYS or keys[0] is None:
         out = ops.lexsort_core(rot, pallas=False)
     else:
-        runs = [rot[i * cap:(i + 1) * cap] for i in range(ndev)]
         valids = [blk[i * cap:(i + 1) * cap, 0] != pad_of(blk)
                   for i in range(ndev)]
         iota = jnp.arange(cap, dtype=jnp.int32)
-        with jax.experimental.enable_x64():
-            keys = ([r[:, 0] for r in runs] if ar == 1
-                    else [ops.pack_rows2(r) for r in runs])
-            ranks = []
-            for i in range(ndev):
-                rank = iota
-                for j in range(ndev):
-                    if j == i:
-                        continue
-                    # right for earlier runs / left for later ones: equal
-                    # rows order by source run, making every slot unique
-                    rank = rank + jnp.searchsorted(
-                        keys[j], keys[i],
-                        side="right" if j < i else "left").astype(jnp.int32)
-                ranks.append(rank)
-        out = jnp.full((n + 1, ar), pad_of(blk), blk.dtype)
+        ranks = []
+        for i in range(ndev):
+            rank = iota
+            for j in range(ndev):
+                if j == i:
+                    continue
+                # right for earlier runs / left for later ones: equal
+                # rows order by source run, making every slot unique
+                rank = rank + jnp.searchsorted(
+                    keys[j], keys[i],
+                    side="right" if j < i else "left").astype(jnp.int32)
+            ranks.append(rank)
+        out = jnp.full((n + 1, ar), pad_of(blk), ops._wide(blk).dtype)
         for i, r in enumerate(runs):
             pos = jnp.where(valids[i], ranks[i], n)    # PAD rows -> trash
-            out = out.at[pos].set(jnp.where(valids[i][:, None], r,
+            out = out.at[pos].set(jnp.where(valids[i][:, None], ops._wide(r),
                                             pad_of(blk)),
                                   mode="drop")
-        out = out[:n]
+        out = out[:n].astype(blk.dtype)
     if identity:
         return out
     inv = [0] * ar
@@ -383,8 +381,8 @@ def _build_dist_round(mesh, axis, ndev, preds, caps, active, delta_in,
                  tuple(P(axis) for _ in derived),
                  tuple(P() for _ in derived),
                  P(), P())
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs))
     return fn, ovf_labels, derived
 
 
@@ -733,11 +731,16 @@ def _build_dist_fixpoint(mesh, axis, ndev, s_preds, o_preds, caps, active,
             return jnp.logical_and(jnp.logical_and(live > 0, ok),
                                    rounds < max_rounds)
 
+        def varying(x):
+            # the body derives tails and their counts from shard-local
+            # rows, so the carry must start varying over the mesh axis
+            return jax.lax.pcast(x, axis, to="varying")
+
         state = (
-            tuple(jnp.full((tail_caps[p], base[p].shape[1]),
-                           pad_of(base[p]), base[p].dtype)
+            tuple(varying(jnp.full((tail_caps[p], base[p].shape[1]),
+                                   pad_of(base[p]), base[p].dtype))
                   for p in s_preds),
-            tuple(jnp.zeros((), jnp.int32) for _ in s_preds),
+            tuple(varying(jnp.zeros((), jnp.int32)) for _ in s_preds),
             tuple(deltas0[p] for p in s_preds),
             d_counts0,
             tuple(carried0),
@@ -758,8 +761,8 @@ def _build_dist_fixpoint(mesh, axis, ndev, s_preds, o_preds, caps, active,
                  tuple(P(axis, None) for _ in s_preds),
                  tuple(P(axis) for _ in s_preds),
                  P(), P(), P(), P())
-    return (jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)),
+    return (jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                  out_specs=out_specs)),
             ovf_labels)
 
 

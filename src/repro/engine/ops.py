@@ -40,17 +40,17 @@ the fast paths for A/B benchmarking.
 
 Kernel dispatch
 ---------------
-Setting ``REPRO_USE_PALLAS=1`` routes the sort / unique-mask / membership-
-probe inner loops through the Pallas kernels in ``repro.kernels.ops``
-(``sort_with_payload``, ``unique_mask``, ``probe_sorted``; interpret mode on
-CPU, compiled on TPU).  The jnp implementations here are the reference path
-and the default.  Multi-column lexsorts and the merge-union binary searches
-stay on the jnp path in both modes (the kernels are single-key).
+Setting ``REPRO_USE_PALLAS=1`` routes the single-key sort and unique-mask
+inner loops through the Pallas kernels in ``repro.kernels.ops``
+(``sort_with_payload``, ``unique_mask``; interpret mode on the CPU backend,
+compiled on TPU).  The jnp implementations here are the reference path and
+the default.  Multi-column lexsorts, membership probes and the merge-union
+binary searches stay on the jnp path in both modes.
 
 Env-flag matrix
 ---------------
 =================== ======= ====================================================
-``REPRO_USE_PALLAS`` ``0``   Pallas kernels for sort/unique/probe inner loops
+``REPRO_USE_PALLAS`` ``0``   Pallas kernels for sort/unique inner loops
 ``REPRO_SORTED_STORE`` ``1`` sortedness markers + incremental merge-union
 ``REPRO_FUSED``      ``0``   fused round executor (one XLA program per round)
 ``REPRO_DIST``       ``0``   sharded shard_map executor over all local devices
@@ -75,7 +75,7 @@ from repro.engine.relation import (PAD, Relation, lex_order, next_pow2,
 # dispatch switches + sort-pass / host-sync accounting
 # ---------------------------------------------------------------------------
 def use_pallas() -> bool:
-    """Route sort/unique/probe inner loops through the Pallas kernels."""
+    """Route sort/unique inner loops through the Pallas kernels."""
     return os.environ.get("REPRO_USE_PALLAS", "0") == "1"
 
 
@@ -191,6 +191,18 @@ def _is_pow2(n: int) -> bool:
     return n > 0 and (n & (n - 1)) == 0
 
 
+def _kernel_width(data) -> bool:
+    """The Pallas kernels take 32-bit words: int64 stores stay on jnp."""
+    return data.dtype.itemsize <= 4
+
+
+def _wide(x):
+    """int32 view of narrower integer data (a no-op at 32 bits and up).
+    Sort keys and scattered rows go through it: on a TPU v5e an int16
+    store lost facts through these ops, which it does not at 32 bits."""
+    return x.astype(jnp.int32) if x.dtype.itemsize < 4 else x
+
+
 # ===========================================================================
 # traceable cores — pure jnp, shape-stable, no host interaction.  Safe to
 # call inside jit / while_loop / shard_map; static args (column indices,
@@ -205,11 +217,10 @@ def lexsort_core(data, pallas: bool | None = None):
         pallas = use_pallas()
     if pallas and ar == 1 and _is_pow2(cap):
         return keysort_core(data, 0, pallas=True)
-    if ar == 2 and _pack_ok(data.dtype):
-        with jax.experimental.enable_x64():
-            order = jnp.argsort(pack_rows2(data)).astype(jnp.int32)
-        return data[order]
-    keys = tuple(data[:, c] for c in reversed(range(ar)))
+    key = scalar_key(data)
+    if key is not None:
+        return data[jnp.argsort(key)]
+    keys = tuple(_wide(data[:, c]) for c in reversed(range(ar)))
     return data[jnp.lexsort(keys)]
 
 
@@ -218,20 +229,20 @@ def keysort_core(data, key_col: int, pallas: bool | None = None):
     cap = data.shape[0]
     if pallas is None:
         pallas = use_pallas()
-    if pallas and _is_pow2(cap):
+    if pallas and _is_pow2(cap) and _kernel_width(data):
         K = _kernels()
         vals = jnp.arange(cap, dtype=jnp.int32)
         _, perm = K.sort_with_payload(data[:, key_col], vals,
                                       tile=min(1024, cap))
         return data[perm]
-    return data[jnp.argsort(data[:, key_col])]
+    return data[jnp.argsort(_wide(data[:, key_col]))]
 
 
 def dedup_mask_core(sorted_data, pallas: bool | None = None):
     """First-occurrence mask over lexsorted rows (PAD rows excluded)."""
     if pallas is None:
         pallas = use_pallas()
-    if pallas:
+    if pallas and _kernel_width(sorted_data):
         K = _kernels()
         return K.unique_mask(sorted_data).astype(bool)
     prev = jnp.roll(sorted_data, 1, axis=0)
@@ -259,9 +270,10 @@ def compact_core(data, mask, out_cap: int):
     ``sum(mask) > out_cap``."""
     pos = jnp.cumsum(mask) - 1
     idx = jnp.where(mask, pos, out_cap)
-    out = jnp.full((out_cap + 1, data.shape[1]), pad_of(data), data.dtype)
-    out = out.at[idx].set(data, mode="drop")
-    return out[:out_cap]
+    rows = _wide(data)
+    out = jnp.full((out_cap + 1, data.shape[1]), pad_of(data), rows.dtype)
+    out = out.at[idx].set(rows, mode="drop")
+    return out[:out_cap].astype(data.dtype)
 
 
 def project_core(data, cols):
@@ -274,8 +286,8 @@ def project_core(data, cols):
 def join_count_core(ldata, rdata_sorted, lkey: int, rkey: int):
     """Count pass of the sort-merge join: per-left-row match ranges in the
     right block (sorted by ``rkey``).  Returns (total, per, cum, lo)."""
-    lk = ldata[:, lkey]
-    rk = rdata_sorted[:, rkey]
+    lk = _wide(ldata[:, lkey])
+    rk = _wide(rdata_sorted[:, rkey])
     lo = jnp.searchsorted(rk, lk, side="left")
     hi = jnp.searchsorted(rk, lk, side="right")
     per = jnp.where(lk != pad_of(ldata), hi - lo, 0)
@@ -324,85 +336,45 @@ def _range_narrow(col, key, lo, hi):
     return bs(False), bs(True)
 
 
-# pack target per store dtype: two narrow columns bitcast into one key of
-# double the width.  int64 rows have no 128-bit key — they take the
-# per-column binary-search path instead (the honest wide-baseline cost).
-_PACK_KEY = {
-    np.dtype(np.int16): jnp.int32,
-    np.dtype(np.int32): jnp.int64,
-}
-
-
-def pack_rows2(rows):
-    """Pack (cap, 2) non-negative narrow rows into one double-width key per
-    row that preserves lexicographic order (dictionary ids are non-negative
-    and PAD = dtype max, so packed PAD rows stay lex-maximal).  Turns the
-    per-column binary-search loops into single XLA-native sort/searchsorted
-    calls for the dominant arity-2 case.
-
-    Implemented as a bitcast (low word first — little-endian on CPU/GPU)
-    rather than shift-add: with the global x64 flag off, int64 *constants*
-    are canonicalized to int32 during lowering, but a constant-free bitcast
-    survives; ``enable_x64`` covers the trace-time aval creation (a no-op
-    for the int16 -> int32 pack, which never leaves 32-bit)."""
-    out_dt = _PACK_KEY[np.dtype(rows.dtype)]
-    with jax.experimental.enable_x64():
-        pair = jnp.stack([rows[:, 1], rows[:, 0]], axis=1)
-        return jax.lax.bitcast_convert_type(pair, out_dt)
+def scalar_key(rows):
+    """The order-preserving scalar key of arity-1 rows (their one column,
+    as int32), or None: wider rows sort by all their columns and search
+    column by column."""
+    if rows.shape[1] == 1:
+        return _wide(rows[:, 0])
+    return None
 
 
 def lex_range_core(hay_sorted, probe):
     """Per-probe-row [lo, hi) occurrence range in a lexsorted haystack:
     per-column range narrowing; when a column value is absent the range
     collapses to the insertion point and stays there."""
-    lo = jnp.zeros(probe.shape[0], jnp.int32)
-    hi = jnp.full(probe.shape[0], hay_sorted.shape[0], jnp.int32)
+    # *_like of a probe column: under shard_map the loop carry then starts
+    # with the probe's varying-axes type, as the fori_loop body produces
+    lo = jnp.zeros_like(probe[:, 0], jnp.int32)
+    hi = jnp.full_like(probe[:, 0], hay_sorted.shape[0], jnp.int32)
     for c in range(hay_sorted.shape[1]):
         lo, hi = _range_narrow(hay_sorted[:, c], probe[:, c], lo, hi)
     return lo, hi
 
 
-def _pack_ok(dtype=np.int32) -> bool:
-    """Whether arity-2 rows of ``dtype`` can pack into one scalar key:
-    int16 pairs pack to int32 (native everywhere); int32 pairs pack to
-    int64 (needs a backend with native 64-bit support); int64 pairs have
-    no 128-bit key dtype and fall back to per-column binary search."""
-    dt = np.dtype(dtype)
-    if dt == np.int16:
-        return True
-    if dt == np.int32:
-        return jax.default_backend() != "tpu"
-    return False
-
-
-def _lex_keys(hay, probe):
-    """Order-preserving scalar keys for rows of arity <= 2, else None."""
-    if hay.shape[1] == 1:
-        return hay[:, 0], probe[:, 0]
-    if hay.shape[1] == 2 and _pack_ok(hay.dtype):
-        return pack_rows2(hay), pack_rows2(probe)
-    return None
-
-
 def _lex_searchsorted_left(hay, probe):
     """Leftmost insertion positions of each ``probe`` row in lexsorted
     ``hay``."""
-    keys = _lex_keys(hay, probe)
-    if keys is not None:
-        with jax.experimental.enable_x64():
-            return jnp.searchsorted(keys[0], keys[1], side="left"
-                                    ).astype(jnp.int32)
+    hk = scalar_key(hay)
+    if hk is not None:
+        return jnp.searchsorted(hk, scalar_key(probe), side="left"
+                                ).astype(jnp.int32)
     return lex_range_core(hay, probe)[0]
 
 
 def _lex_searchsorted_right(hay, probe):
     """Rightmost insertion positions of each ``probe`` row in lexsorted
     ``hay``."""
-    keys = _lex_keys(hay, probe)
-    if keys is not None:
-        with jax.experimental.enable_x64():
-            return jnp.searchsorted(keys[0], keys[1], side="right"
-                                    ).astype(jnp.int32)
+    hk = scalar_key(hay)
+    if hk is not None:
+        return jnp.searchsorted(hk, scalar_key(probe), side="right"
+                                ).astype(jnp.int32)
     return lex_range_core(hay, probe)[1]
 
 
@@ -411,50 +383,34 @@ def member_mask_core(probe_rows, hay_sorted):
     rows report non-member: PAD columns never match valid haystack rows and
     match only haystack PAD padding, which is excluded either way)."""
     valid = probe_rows[:, 0] != pad_of(probe_rows)
-    keys = _lex_keys(hay_sorted, probe_rows)
-    if keys is not None:
-        hk, pk = keys
+    hk = scalar_key(hay_sorted)
+    if hk is not None:
+        pk = scalar_key(probe_rows)
         n = hk.shape[0]
-        # int64 stays confined to the key arrays: index math runs in int32
-        # so no int64 constants reach lowering (which would canonicalize
-        # them to int32 under the global x64-off flag)
-        with jax.experimental.enable_x64():
-            idx = jnp.searchsorted(hk, pk).astype(jnp.int32)
-            # no jnp.clip here: it is an internally-jitted helper whose
-            # cached trace clashes across x64 contexts
-            idx_c = jnp.minimum(jnp.maximum(idx, 0), n - 1)
-            found = hk[idx_c] == pk
+        idx = jnp.searchsorted(hk, pk).astype(jnp.int32)
+        found = hk[jnp.clip(idx, 0, n - 1)] == pk
         found = jnp.logical_and(found, idx < n)
         return jnp.logical_and(found, valid)
     lo, hi = lex_range_core(hay_sorted, probe_rows)
     return jnp.logical_and(hi > lo, valid)
 
 
-def anti_keep_core(data, hay_sorted, cols, pallas: bool | None = None):
+def anti_keep_core(data, hay_sorted, cols):
     """Keep-mask for the antijoin: valid rows of ``data`` whose ``cols``
-    tuple does NOT occur in the lexsorted haystack.  Single-column probes
-    route through the Pallas binary-search kernel when ``pallas``."""
-    if pallas is None:
-        pallas = use_pallas()
+    tuple does NOT occur in the lexsorted haystack."""
     valid = data[:, 0] != pad_of(data)
-    if (pallas and hay_sorted.shape[1] == 1 and len(cols) == 1
-            and _is_pow2(data.shape[0]) and _is_pow2(hay_sorted.shape[0])):
-        K = _kernels()
-        found = K.probe_sorted(data[:, cols[0]], hay_sorted[:, 0]) != 0
-    else:
-        found = member_mask_core(project_core(data, cols), hay_sorted)
+    found = member_mask_core(project_core(data, cols), hay_sorted)
     return jnp.logical_and(valid, jnp.logical_not(found))
 
 
-def merge_diff_core(A, B_sorted, out_cap: int, pallas: bool | None = None):
+def merge_diff_core(A, B_sorted, out_cap: int):
     """Sorted set-difference: rows of block A (lexsorted) minus rows of
     lexsorted block B, compacted into a fresh (out_cap, ar) PAD block.
     Mirrors ``merge_core``'s binary-search discipline — every A row is one
     lexicographic membership probe into B, no sort pass — and preserves A's
     order (compaction keeps relative order).  Returns (out, n_kept); overflow
     is ``n_kept > out_cap``, checked by the caller."""
-    keep = anti_keep_core(A, B_sorted, tuple(range(A.shape[1])),
-                          pallas=pallas)
+    keep = anti_keep_core(A, B_sorted, tuple(range(A.shape[1])))
     n = jnp.sum(keep).astype(jnp.int32)
     return compact_core(A, keep, out_cap), n
 
@@ -483,10 +439,10 @@ def merge_core(A, B, na, nb):
     cnt = jnp.cumsum(h)[:out_cap]            # #{valid B rows lex< A[j]}
     pos_a = jnp.where(ia < na, ia + cnt, out_cap)
     pos_b = jnp.where(valid_b, ib + p, out_cap)
-    out = jnp.full((out_cap, ar), pad_of(A), A.dtype)
-    out = out.at[pos_a].set(A, mode="drop")
-    out = out.at[pos_b].set(B, mode="drop")
-    return out
+    out = jnp.full((out_cap, ar), pad_of(A), _wide(A).dtype)
+    out = out.at[pos_a].set(_wide(A), mode="drop")
+    out = out.at[pos_b].set(_wide(B), mode="drop")
+    return out.astype(A.dtype)
 
 
 # ===========================================================================
@@ -665,10 +621,10 @@ def cross(l: Relation, r: Relation):
 # in a sorted haystack relation
 # ---------------------------------------------------------------------------
 @lru_cache(maxsize=None)
-def _anti_count_fn(cap, ar, hcap, har, cols, pallas):
+def _anti_count_fn(cap, ar, hcap, har, cols):
     @jax.jit
     def f(data, hay_sorted):
-        keep = anti_keep_core(data, hay_sorted, cols, pallas=pallas)
+        keep = anti_keep_core(data, hay_sorted, cols)
         return jnp.sum(keep), keep
     return f
 
@@ -686,7 +642,7 @@ def antijoin(rel: Relation, hay: Relation, cols=None) -> Relation:
     assert len(cols) == hay.arity
     hs = lexsort_rows(hay)
     n, keep = _anti_count_fn(rel.capacity, rel.arity, hs.capacity,
-                             hay.arity, cols, use_pallas())(rel.data, hs.data)
+                             hay.arity, cols)(rel.data, hs.data)
     n = int(n)
     HOST_SYNC_STATS.count_pulls += 1
     if n == rel.count:
@@ -796,10 +752,10 @@ def merge_union(a: Relation, b: Relation) -> Relation:
 
 
 @lru_cache(maxsize=None)
-def _diff_fn(cap, hcap, ar, out_cap, pallas):
+def _diff_fn(cap, hcap, ar, out_cap):
     @jax.jit
     def f(A, B):
-        return merge_diff_core(A, B, out_cap, pallas=pallas)
+        return merge_diff_core(A, B, out_cap)
     return f
 
 
@@ -818,8 +774,8 @@ def merge_diff(a: Relation, b: Relation) -> Relation:
     # the shape keeps downstream jit signatures stable across delete calls
     # (a shrink-to-fit here would recompile every store consumer)
     out_cap = a.capacity
-    out, n = _diff_fn(a.capacity, b.capacity, a.arity, out_cap,
-                      use_pallas())(a.data, b.data)
+    out, n = _diff_fn(a.capacity, b.capacity, a.arity, out_cap)(a.data,
+                                                                b.data)
     n = int(n)
     HOST_SYNC_STATS.count_pulls += 1
     SORT_STATS.merges += 1

@@ -340,8 +340,7 @@ def _exec_rule_traced(plan, inputs, pre_data, join_caps, pallas,
             if prefilter is not None:
                 keep = prefilter(data, plan.pre[1])
             else:
-                keep = ops.anti_keep_core(data, pre_data, plan.pre[1],
-                                          pallas=pallas)
+                keep = ops.anti_keep_core(data, pre_data, plan.pre[1])
             data = ops.compact_core(data, keep, data.shape[0])
         if cur is None:
             cur, cur_skey = data, data_skey
@@ -426,15 +425,19 @@ class _Caps:
         self.tail = {}
         self.join = {}
         self.bucket = {}
+        self._delta_guess = next_pow2(max(64, 2 * base))
+        self._bucket_guess = next_pow2(max(32, 2 * base // max(ndev, 1)))
         for pred, (data, count) in stores.items():
             # converged capacities from a previous run of this program
             # dominate the cold-start guess (guesses must not drift upward
-            # with the memoized sizes, or every run re-plans and recompiles)
+            # with the memoized sizes, or every run re-plans and recompiles).
+            # A store that starts empty (a derived predicate) is sized like
+            # a delta: round 1 fills it from the base, and a floor-sized
+            # guess would climb there one recompiled retry at a time
             memo = _CAP_MEMO.get((fp, "store", pred), 0)
-            guess = memo or next_pow2(max(32, 4 * max(count, 1)))
+            guess = memo or (next_pow2(max(32, 4 * count)) if count
+                             else self._delta_guess)
             self.store[pred] = max(guess, next_pow2(max(count, 1)))
-        self._delta_guess = next_pow2(max(64, 2 * base))
-        self._bucket_guess = next_pow2(max(32, 2 * base // max(ndev, 1)))
 
     def delta_cap(self, pred):
         if pred not in self.delta:

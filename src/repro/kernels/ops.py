@@ -1,16 +1,15 @@
-"""Jit'd wrappers around the Pallas kernels.
+"""Jit-able wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` — the
-kernel bodies run through the Pallas interpreter for correctness validation.
-On TPU set ``INTERPRET = False`` (the launch scripts do this when
-``jax.default_backend() == 'tpu'``).
+Interpret mode is chosen when a wrapper is called, not when this module is
+imported: the kernel bodies run through the Pallas interpreter on the CPU
+backend only.  On a TPU a kernel compiles or the call fails; it never falls
+back to the interpreter or to the jnp reference.
 
 Edge shapes: the engine always calls these on pow-2 capacity buckets, but
 the wrappers normalize everything else — empty inputs return immediately,
-non-pow-2 sort lengths are padded to the next power of two with key-space
-maxima (which sort behind every real key, including PAD sentinels that tie
-with them) and sliced back, and tiles are clamped to pow-2 divisors of the
-padded length.
+lengths are padded up to a whole number of tiles (tiles are at least one
+(8, 128) int32 vreg) with key-space maxima or PAD rows and sliced back,
+and narrow keys are widened to int32 for the kernels.
 """
 from __future__ import annotations
 
@@ -19,90 +18,98 @@ import jax.numpy as jnp
 
 from repro.engine.relation import next_pow2, pad_of
 from repro.kernels import bitonic_sort as BS
-from repro.kernels import hash_probe as HP
 from repro.kernels import unique_mask as UM
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    """Pallas interpret mode: on the CPU backend, and nowhere else."""
+    return jax.default_backend() == "cpu"
 
 
 def _pow2_tile(tile: int, n: int) -> int:
-    """Largest pow-2 tile <= min(tile, n); n must itself be pow-2."""
-    t = max(1, min(tile, n))
+    """Pow-2 tile of at least one vreg, at most ``max(tile, n)`` rounded
+    down to a power of two."""
+    t = max(BS.MIN_TILE, min(tile, next_pow2(n)))
     return 1 << (t.bit_length() - 1)
 
 
+def _order_key(keys):
+    """Order-preserving int32 image of int32/int16/uint32 keys."""
+    if keys.dtype == jnp.uint32:
+        return jax.lax.bitcast_convert_type(keys ^ jnp.uint32(1 << 31),
+                                            jnp.int32)
+    if not jnp.issubdtype(keys.dtype, jnp.signedinteger) or \
+            keys.dtype.itemsize > 4:
+        raise TypeError(f"sort keys of {keys.dtype}: the network sorts "
+                        "32-bit words")
+    return keys.astype(jnp.int32)
+
+
 def sort_with_payload(keys, vals, tile: int = 1024):
-    """Full sort of (n,) int32/uint32 keys + payload: tile-sort kernel +
-    log-depth pairwise bitonic merge kernels.  Non-pow-2 lengths are padded
-    with the key dtype's max; because real keys may equal that sentinel (the
-    engine's PAD) and the bitonic network is unstable, the network sorts
-    POSITIONS as its payload there — synthetic positions (>= n) are
-    compacted out afterwards and the caller's payload gathered back, so the
-    returned payload is always a permutation of the caller's, whatever its
-    values."""
+    """Full sort of (n,) keys + payload through the bitonic network: the
+    in-block stages in the Pallas kernel, the cross-block stages as XLA
+    passes between its calls.  The network sorts (key, position) pairs —
+    positions past ``n`` belong to padding, which is compacted out
+    afterwards, so real keys may equal the padding sentinel (the engine's
+    PAD) — and the caller's keys and payload are gathered by the resulting
+    permutation, which is therefore always a permutation of the input."""
     n = keys.shape[0]
     if n == 0:
         return keys, vals
-    m = next_pow2(n)
-    t = _pow2_tile(tile, m)
+    interpret = _interpret()
+    t = _pow2_tile(tile, n)
+    m = max(next_pow2(n), t)
+    k = _order_key(keys)
     if m != n:
-        sentinel = jnp.iinfo(keys.dtype).max
-        keys_p = jnp.concatenate(
-            [keys, jnp.full((m - n,), sentinel, keys.dtype)])
-        pos = jnp.arange(m, dtype=jnp.int32)
-        keys_p, pos = BS.bitonic_sort_tiles(keys_p, pos, t,
-                                            interpret=INTERPRET)
-        width = t * 2
-        while width <= m:
-            keys_p, pos = BS.bitonic_merge_pairs(keys_p, pos, width,
-                                                 interpret=INTERPRET)
-            width *= 2
-        # drop the synthetic entries (position >= n), keeping sorted order:
-        # they only interleave with real entries inside the sentinel-key tie
+        k = jnp.concatenate(
+            [k, jnp.full((m - n,), jnp.iinfo(jnp.int32).max, jnp.int32)])
+    pos = jnp.arange(m, dtype=jnp.int32)
+    shape2d = (m // BS.LANES, BS.LANES)
+    sizes = []
+    size = 2
+    while size <= t:
+        sizes.append(size)
+        size *= 2
+    k, pos = BS.network_stages(k.reshape(shape2d), pos.reshape(shape2d), t,
+                               sizes, interpret=interpret)
+    while size <= m:
+        k, pos = k.reshape(m), pos.reshape(m)
+        j = size // 2
+        while j >= t:
+            k, pos = BS.cmp_exchange_xla(k, pos, j, size)
+            j //= 2
+        k, pos = BS.network_stages(k.reshape(shape2d), pos.reshape(shape2d),
+                                   t, (size,), interpret=interpret)
+        size *= 2
+    pos = pos.reshape(m)
+    if m != n:
+        # drop the padding positions (>= n), keeping sorted order: they
+        # only interleave with real entries inside the sentinel-key tie
         # group, so an order-preserving compaction is still sorted by key
         keep = pos < n
         slot = jnp.where(keep, jnp.cumsum(keep) - 1, n)
-        ks = jnp.zeros((n + 1,), keys.dtype).at[slot].set(keys_p,
-                                                          mode="drop")
-        perm = jnp.zeros((n + 1,), jnp.int32).at[slot].set(pos, mode="drop")
-        return ks[:n], vals[perm[:n]]
-    keys, vals = BS.bitonic_sort_tiles(keys, vals, t, interpret=INTERPRET)
-    width = t * 2
-    while width <= m:
-        keys, vals = BS.bitonic_merge_pairs(keys, vals, width,
-                                            interpret=INTERPRET)
-        width *= 2
-    return keys, vals
-
-
-def _pad_to_tile(n: int, tile: int):
-    """(pow-2 tile, padded length that the tile divides)."""
-    t = _pow2_tile(tile, n)
-    return t, ((n + t - 1) // t) * t
+        pos = jnp.zeros((n + 1,), jnp.int32).at[slot].set(pos, mode="drop")
+        pos = pos[:n]
+    return keys[pos], vals[pos]
 
 
 def unique_mask(data, tile: int = 1024):
+    """(n,) int32 first-occurrence mask over lexsorted (n, C) rows."""
     n = data.shape[0]
     if n == 0:
         return jnp.zeros((0,), jnp.int32)
-    t, m = _pad_to_tile(n, tile)
-    if m != n:
-        # pad with PAD rows: they are masked out by the kernel and sliced off
-        data = jnp.concatenate(
-            [data, jnp.full((m - n, data.shape[1]), pad_of(data),
-                            data.dtype)])
-    return UM.unique_mask(data, tile=t, interpret=INTERPRET)[:n]
-
-
-def probe_sorted(queries, hay_sorted, tile: int = 1024):
-    n = queries.shape[0]
-    if n == 0:
-        return jnp.zeros((0,), jnp.int32)
-    if hay_sorted.shape[0] == 0:
-        return jnp.zeros((n,), jnp.int32)
-    t, m = _pad_to_tile(n, tile)
-    if m != n:
-        queries = jnp.concatenate(
-            [queries, jnp.zeros((m - n,), queries.dtype)])
-    return HP.probe_sorted(queries, hay_sorted, tile=t,
-                           interpret=INTERPRET)[:n]
+    if data.dtype.itemsize > 4:
+        raise TypeError(f"rows of {data.dtype}: the kernel compares 32-bit "
+                        "words")
+    t = _pow2_tile(tile, n)
+    m = -(-n // t) * t
+    pad = pad_of(data)
+    cols = []
+    for c in range(data.shape[1]):
+        col = data[:, c].astype(jnp.int32)
+        if m != n:
+            # PAD rows are masked out by the kernel and sliced off
+            col = jnp.concatenate([col, jnp.full((m - n,), pad, jnp.int32)])
+        cols.append(col.reshape(m // UM.LANES, UM.LANES))
+    out = UM.unique_mask(cols, pad, t, interpret=_interpret())
+    return out.reshape(m)[:n]

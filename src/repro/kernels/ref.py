@@ -1,7 +1,6 @@
 """Pure-jnp oracles for every Pallas kernel (allclose targets for tests)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.engine.relation import PAD
@@ -14,20 +13,6 @@ def sort_with_payload_ref(keys, vals):
     return keys[order], vals[order]
 
 
-def sort_tiles_ref(keys, vals, tile: int):
-    n = keys.shape[0]
-    kk = keys.reshape(n // tile, tile)
-    vv = vals.reshape(n // tile, tile)
-    order = jnp.argsort(kk, axis=1)
-    return (jnp.take_along_axis(kk, order, axis=1).reshape(n),
-            jnp.take_along_axis(vv, order, axis=1).reshape(n))
-
-
-def merge_pairs_ref(keys, vals, tile: int):
-    """Adjacent sorted blocks of tile//2 merged into sorted blocks of tile."""
-    return sort_tiles_ref(keys, vals, tile)
-
-
 def unique_mask_ref(data):
     prev = jnp.concatenate(
         [jnp.full((1, data.shape[1]), PAD, data.dtype), data[:-1]], axis=0)
@@ -35,9 +20,3 @@ def unique_mask_ref(data):
     neq = neq.at[0].set(True)
     valid = data[:, 0] != PAD
     return jnp.logical_and(neq, valid).astype(jnp.int32)
-
-
-def probe_sorted_ref(queries, hay_sorted):
-    idx = jnp.searchsorted(hay_sorted, queries)
-    found = hay_sorted[jnp.clip(idx, 0, hay_sorted.shape[0] - 1)] == queries
-    return jnp.logical_and(found, idx < hay_sorted.shape[0]).astype(jnp.int32)

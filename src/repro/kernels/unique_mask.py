@@ -1,9 +1,12 @@
 """Adjacent-unique mask Pallas kernel over lexsorted rows.
 
-Given sorted row-major (N, C) int32 data, emits mask[i] = 1 iff row i differs
-from row i-1 (and is not padding).  This is the dedup core fused after the
-sort (GLog's duplicate elimination).  Block boundaries read one overlapping
-row via a shifted input block.
+Given lexsorted rows, emits mask[i] = 1 iff row i differs from row i-1 and
+is not padding.  This is the dedup core fused after the sort (GLog's
+duplicate elimination).
+
+Each column travels as its own lane-dense (n // 128, 128) int32 array, with
+a second copy shifted down by one row supplying row i-1, so a block is a
+plain (rows, 128) tile: an (n, C) block would pad C up to 128 lanes.
 """
 from __future__ import annotations
 
@@ -13,38 +16,42 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.engine.relation import pad_of
+LANES = 128
+MIN_TILE = 8 * LANES       # one (8, 128) int32 vreg: the smallest block
 
 
-def _unique_kernel(cur_ref, prev_ref, out_ref):
-    i = pl.program_id(0)
-    cur = cur_ref[...]                       # (tile, C)
-    prev = prev_ref[...]                     # (tile, C): rows shifted by -1
-    neq = jnp.any(cur != prev, axis=1)
-    first_global = jnp.logical_and(i == 0,
-                                   jax.lax.broadcasted_iota(
-                                       jnp.int32, neq.shape, 0) == 0)
-    valid = cur[:, 0] != pad_of(cur)
-    out_ref[...] = jnp.where(
-        jnp.logical_and(valid, jnp.logical_or(neq, first_global)), 1, 0
-    ).astype(jnp.int32)
+def _unique_kernel(*refs, n_cols: int, pad: int):
+    cur = refs[:n_cols]
+    prev = refs[n_cols:2 * n_cols]
+    out_ref = refs[2 * n_cols]
+    c0 = cur[0][...]
+    neq = c0 != prev[0][...]
+    for c in range(1, n_cols):
+        neq = jnp.logical_or(neq, cur[c][...] != prev[c][...])
+    out_ref[...] = jnp.where(jnp.logical_and(neq, c0 != pad), 1, 0
+                             ).astype(jnp.int32)
 
 
-def unique_mask(data, tile: int = 1024, *, interpret: bool = True):
-    """data: (N, C) int32 lexsorted (PAD rows last).  Returns (N,) int32."""
-    N, C = data.shape
-    assert N % tile == 0, (N, tile)
-    # shifted copy supplies row i-1; row -1 is a PAD row (compares unequal
-    # to any valid row, equal only to other PAD rows which are masked out)
-    shifted = jnp.concatenate(
-        [jnp.full((1, C), pad_of(data), data.dtype), data[:-1]], axis=0)
-    grid = (N // tile,)
+def unique_mask(cols, pad: int, tile: int, *, interpret: bool):
+    """cols: C lane-dense (n // 128, 128) int32 columns of lexsorted rows
+    (PAD rows last), ``n % tile == 0``.  Row -1 reads as a PAD row, which
+    differs from every valid row.  Returns the (n // 128, 128) int32 mask."""
+    n_rows = cols[0].shape[0]
+    rows = tile // LANES
+    assert tile >= MIN_TILE and (tile & (tile - 1)) == 0
+    assert n_rows % rows == 0
+    shifted = []
+    for c in cols:
+        flat = c.reshape(-1)
+        shifted.append(jnp.concatenate(
+            [jnp.full((1,), pad, flat.dtype), flat[:-1]]).reshape(c.shape))
+    spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
     return pl.pallas_call(
-        functools.partial(_unique_kernel),
-        grid=grid,
-        in_specs=[pl.BlockSpec((tile, C), lambda i: (i, 0)),
-                  pl.BlockSpec((tile, C), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((N,), jnp.int32),
+        functools.partial(_unique_kernel, n_cols=len(cols), pad=pad),
+        grid=(n_rows // rows,),
+        in_specs=[spec] * (2 * len(cols)),
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(cols[0].shape, jnp.int32),
+        name="unique_mask",
         interpret=interpret,
-    )(data, shifted)
+    )(*cols, *shifted)

@@ -9,29 +9,17 @@ accounts separately.
 """
 from __future__ import annotations
 
-import jax
+import math
 
-try:  # jax >= 0.5: explicit axis types on mesh construction
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x (the pinned 0.4.37): no AxisType
-    AxisType = None
+import jax
+from jax.sharding import AxisType
 
 from repro.models.layers import MeshCtx
 
 
-import math
-
-
-def compat_make_mesh(shape, axis_names, *, devices=None):
-    """``jax.make_mesh`` across jax versions: pass ``axis_types`` when the
-    installed jax supports it, fall back to a plain mesh otherwise."""
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axis_names, devices=devices,
-                                 axis_types=(AxisType.Auto,) * len(axis_names))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axis_names, devices=devices)
+def _auto_mesh(shape, axis_names, devices=None):
+    return jax.make_mesh(shape, axis_names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -39,7 +27,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = math.prod(shape)
     devs = jax.devices()[:n]
-    return compat_make_mesh(shape, axes, devices=devs)
+    return _auto_mesh(shape, axes, devices=devs)
 
 
 def make_mesh_ctx(mesh) -> MeshCtx:
@@ -51,14 +39,14 @@ def make_mesh_ctx(mesh) -> MeshCtx:
 
 def make_host_mesh(dp: int = 1, tp: int = 1):
     """Small mesh over however many local devices exist (tests/examples)."""
-    return compat_make_mesh((dp, tp), ("data", "model"))
+    return _auto_mesh((dp, tp), ("data", "model"))
 
 
 def make_data_mesh(ndev: int | None = None):
     """Pure data-parallel mesh for the sharded materializer: the first
     ``ndev`` (default: all) local devices on the "data" axis."""
     n = ndev if ndev is not None else len(jax.devices())
-    return compat_make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
 
 
 def axis_size(mesh, axis) -> int:

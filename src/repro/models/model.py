@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import layers as L
 from repro.models import ssm as SSM

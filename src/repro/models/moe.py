@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import MeshCtx
-from repro.compat import shard_map
+from jax import shard_map
 
 
 def init_moe(cfg, rng):

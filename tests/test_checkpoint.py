@@ -10,7 +10,7 @@ import pytest
 
 from repro.configs.base import get_smoke_config
 from repro.data.pipeline import KBLinearizer, SyntheticTokens
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.models.layers import MeshCtx
 from repro.train import optimizer as OPT
@@ -19,7 +19,7 @@ from repro.train.train_loop import train
 
 
 def _mcx():
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     return MeshCtx(mesh=mesh, dp=("data",), tp="model")
 
 

@@ -73,24 +73,26 @@ def test_lexsort_core_matches_np_lexsort(dtype):
 
 @pytest.mark.parametrize("dtype", [np.int16, np.int32])
 def test_pack_rows2_roundtrip_order(dtype):
-    """The packed double-width key must sort identically to row-major
-    lexicographic order, including values adjacent to the PAD sentinel."""
+    """Arity-2 rows have no packed scalar key at any width: they sort by
+    both columns, in row-major lexicographic order, including values
+    adjacent to the PAD sentinel."""
     dt = np.dtype(dtype)
     hi = id_range(dt)[1]
-    rows = np.array([[0, 0], [0, hi], [hi, 0], [hi, hi], [1, hi - 1]], dt)
+    pad = hi + 1
+    rows = np.array([[0, 0], [0, hi], [hi, 0], [hi, hi], [1, hi - 1],
+                     [pad, pad], [hi, pad]], dt)
     import jax.numpy as jnp
-    keys = np.asarray(ops.pack_rows2(jnp.asarray(rows)))
-    np_order = np.lexsort(rows.T[::-1])
-    key_order = np.argsort(keys, kind="stable")
-    assert (np_order == key_order).all()
+    assert ops.scalar_key(jnp.asarray(rows)) is None
+    got = np.asarray(ops.lexsort_core(jnp.asarray(rows), pallas=False))
+    assert (got == rows[np.lexsort(rows.T[::-1])]).all()
 
 
 def test_member_mask_pack_vs_binary_search_fallback():
-    """int32 rows take the packed-key path; the same query through the
-    per-column binary-search core (the int64/wide fallback) must agree."""
+    """int16 rows take the per-column binary search; membership must agree
+    with the range search it is built on and with a python set."""
     rng = np.random.default_rng(11)
-    hay = np.unique(rng.integers(0, 60, (80, 2)).astype(np.int32), axis=0)
-    probe = rng.integers(0, 60, (40, 2)).astype(np.int32)
+    hay = np.unique(rng.integers(0, 60, (80, 2)).astype(np.int16), axis=0)
+    probe = rng.integers(0, 60, (40, 2)).astype(np.int16)
     import jax.numpy as jnp
     hay_j, probe_j = jnp.asarray(hay), jnp.asarray(probe)
     packed = np.asarray(ops.member_mask_core(probe_j, hay_j))
@@ -252,6 +254,18 @@ def test_encode_columns_roundtrip_property():
                 assert d.encode(a) == ia and d.encode(b) == ib
 
     check()
+
+
+def test_encode_columns_mixed_str_int():
+    """A column mixing str and int terms cannot be sorted; it interns term
+    by term, each value in its own store, and round-trips."""
+    d = Dictionary(np.int32)
+    arr = np.array([(0, ""), ("a", 7), (0, "a")], dtype=object)
+    ids = d.encode_columns(arr)
+    assert ids.dtype == np.dtype(np.int32)
+    assert [[d.decode(int(i)) for i in row] for row in ids] == \
+        [[0, ""], ["a", 7], [0, "a"]]
+    assert ids[0, 0] == ids[2, 0] and ids[1, 0] == ids[2, 1]
 
 
 def test_encode_many_matches_encode():

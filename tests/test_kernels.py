@@ -11,11 +11,13 @@ from repro.kernels import ref as R
 
 
 @pytest.mark.parametrize("n,tile", [(64, 64), (256, 64), (1024, 256),
-                                    (2048, 512), (4096, 4096)])
-@pytest.mark.parametrize("dtype", [np.int32, np.uint32])
+                                    (2048, 512), (4096, 4096),
+                                    (8192, 1024)])
+@pytest.mark.parametrize("dtype", [np.int32, np.uint32, np.int16])
 def test_bitonic_sort_sweep(n, tile, dtype):
     rng = np.random.default_rng(n + tile)
-    keys = jnp.asarray(rng.integers(0, 1 << 20, n).astype(dtype))
+    hi = min(1 << 20, int(np.iinfo(dtype).max))
+    keys = jnp.asarray(rng.integers(0, hi, n).astype(dtype))
     vals = jnp.arange(n, dtype=jnp.int32)
     ks, vs = K.sort_with_payload(keys, vals, tile=tile)
     np.testing.assert_array_equal(np.asarray(ks), np.sort(np.asarray(keys)))
@@ -37,17 +39,6 @@ def test_unique_mask_sweep(n, c, tile):
                                data[-k:]])
     got = K.unique_mask(jnp.asarray(data), tile=tile)
     want = R.unique_mask_ref(jnp.asarray(data))
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-@pytest.mark.parametrize("nq,nh,tile", [(64, 16, 64), (256, 100, 128),
-                                        (1024, 1, 256), (512, 511, 512)])
-def test_probe_sweep(nq, nh, tile):
-    rng = np.random.default_rng(nq + nh)
-    hay = np.unique(rng.integers(0, 4 * nh, nh).astype(np.int32))
-    q = jnp.asarray(rng.integers(0, 4 * nh, nq).astype(np.int32))
-    got = K.probe_sorted(q, jnp.asarray(hay), tile=tile)
-    want = R.probe_sorted_ref(q, jnp.asarray(hay))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -151,41 +142,3 @@ def test_unique_mask_all_duplicates():
     want = R.unique_mask_ref(data)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert int(got.sum()) == 1
-
-
-def test_probe_empty_queries():
-    got = K.probe_sorted(jnp.zeros((0,), jnp.int32),
-                         jnp.arange(4, dtype=jnp.int32))
-    assert got.shape == (0,)
-
-
-def test_probe_empty_haystack():
-    q = jnp.arange(64, dtype=jnp.int32)
-    got = K.probe_sorted(q, jnp.zeros((0,), jnp.int32))
-    assert (np.asarray(got) == 0).all()
-
-
-@pytest.mark.parametrize("nq,nh", [(1, 1), (100, 37), (300, 3)])
-def test_probe_non_pow2(nq, nh):
-    rng = np.random.default_rng(nq * nh)
-    hay = np.unique(rng.integers(0, 4 * nh, nh).astype(np.int32))
-    q = jnp.asarray(rng.integers(0, 4 * nh, nq).astype(np.int32))
-    got = K.probe_sorted(q, jnp.asarray(hay))
-    want = R.probe_sorted_ref(q, jnp.asarray(hay))
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_probe_all_pad_queries():
-    """PAD queries only match a PAD entry in the haystack — against a
-    valid-only haystack they must all miss."""
-    q = jnp.full((64,), PAD, jnp.int32)
-    hay = jnp.arange(16, dtype=jnp.int32)
-    got = K.probe_sorted(q, hay)
-    assert (np.asarray(got) == 0).all()
-
-
-def test_probe_all_duplicate_haystack():
-    q = jnp.array([4, 5, 6], jnp.int32)
-    hay = jnp.full((32,), 5, jnp.int32)
-    got = K.probe_sorted(q, hay)
-    np.testing.assert_array_equal(np.asarray(got), [0, 1, 0])

@@ -7,14 +7,14 @@ import pytest
 
 from repro.configs.base import ARCHS, get_config, get_smoke_config, SHAPES, \
     supported_cells
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.models.layers import MeshCtx
 from repro.train import optimizer as OPT
 
 
 def _mcx():
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     return MeshCtx(mesh=mesh, dp=("data",), tp="model")
 
 
