@@ -10,12 +10,15 @@ Everything runs in this one process, through the entry points a user calls
 (``EngineKB.from_stream`` / ``EngineKB(...)`` and ``materialize``) on the
 compiled executor (``REPRO_FUSED=1``):
 
-1. int16 store: TC-WIDE at the largest size whose node ids fit int16.
-2. ``REPRO_USE_PALLAS=1``: TC-WIDE at 10^6 facts through the Pallas sort
+1. LUBM-L against the symbolic chase: equal decoded fact sets.
+2. int16 store: TC-WIDE at the largest size whose node ids fit int16.
+3. ``REPRO_USE_PALLAS=1``: TC-WIDE at 10^6 facts through the Pallas sort
    and unique-mask kernels.
-3. LUBM-L against the symbolic chase: equal decoded fact sets.
 4. TC-WIDE (``STREAM_SCENARIOS["TC-WIDE"]``) at ``TC_FACTS`` total facts on
-   an int32 store; the device's peak memory is printed.
+   an int32 store.
+
+The device's memory in use and its peak so far are printed after each
+phase; LUBM-L runs first, so the first peak is its own.
 
 A TC-WIDE phase passes when its fact count equals ``tc_wide_total``
 exactly (missing facts are listed when it does not) and the run stayed on
@@ -37,14 +40,14 @@ import os
 import sys
 import time
 
-# Cold runs are compile-bound (XLA's TPU sort compiles in 20-30 s per
-# program at these capacities), so sizes are cut to keep the one-chip run
-# well inside 20 minutes with nothing cached: see PERF.md.
-TC_FACTS = 5 * 10 ** 6
+# Cold runs are compile-bound (an XLA TPU sort of 2^16 rows or more takes
+# 20-30 s to compile), so the sizes are those whose phases, measured cold
+# on one v5e chip, add up to well under 20 minutes: see PERF.md.
+TC_FACTS = 10 ** 7
 TC_FACTS_FOUR_CHIPS = 5 * 10 ** 3
 PALLAS_TC_FACTS = 10 ** 6
 INT16_CHAINS = 6_500           # 5 node ids per chain: 32,500 < int16 PAD
-LUBM_UNIVERSITIES = 2
+LUBM_UNIVERSITIES = 12         # at 14 the stores pass 2^15 rows: see PERF.md
 
 
 class CheckFailed(RuntimeError):
@@ -156,9 +159,6 @@ def one_chip(dev, compiles):
     def tc_big():
         kb, st, n = run_tc_wide(TC_FACTS)
         check_tc(kb, st, n, f"TC-WIDE {TC_FACTS:.0e} int32")
-        stats = dev.memory_stats() or {}
-        print(f"  peak_bytes_in_use {stats.get('peak_bytes_in_use')}",
-              flush=True)
 
     def lubm():
         B = lubm_facts(n_univ=LUBM_UNIVERSITIES)
@@ -187,10 +187,13 @@ def one_chip(dev, compiles):
             os.environ.pop("REPRO_USE_PALLAS")
         check_tc(kb, st, n, f"TC-WIDE {PALLAS_TC_FACTS:.0e} Pallas")
 
-    phase("int16", compiles, int16)
-    phase("pallas", compiles, pallas)
-    phase("lubm-l", compiles, lubm)
-    phase("tc-wide", compiles, tc_big)
+    for name, fn in (("lubm-l", lubm), ("int16", int16), ("pallas", pallas),
+                     ("tc-wide", tc_big)):
+        phase(name, compiles, fn)
+        stats = dev.memory_stats() or {}
+        print(f"  bytes_in_use {stats.get('bytes_in_use')}, "
+              f"peak_bytes_in_use {stats.get('peak_bytes_in_use')}",
+              flush=True)
 
 
 def four_chips(compiles):

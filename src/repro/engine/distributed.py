@@ -146,8 +146,8 @@ def _route_to_buckets(rows, target, ndev, bucket_cap, sort_cols=None):
     if sort_cols is None:
         order = jnp.argsort(target)
     else:                 # lexsort: LAST key is primary -> target, then cols
-        order = jnp.lexsort(tuple(ops._wide(rows[:, c])
-                                  for c in reversed(sort_cols)) + (target,))
+        order = jnp.lexsort(tuple(rows[:, c] for c in reversed(sort_cols))
+                            + (target,))
     t_sorted = target[order]
     rows_sorted = rows[order]
     pos = jnp.arange(cap) - jnp.searchsorted(t_sorted, t_sorted, side="left")
@@ -155,13 +155,12 @@ def _route_to_buckets(rows, target, ndev, bucket_cap, sort_cols=None):
                      ndev * bucket_cap)
     overflow = jnp.logical_and(t_sorted < ndev, pos >= bucket_cap)
     slot = jnp.where(overflow, ndev * bucket_cap, slot)
-    wide = ops._wide(rows_sorted)
-    buckets = jnp.full((ndev * bucket_cap + 1, ar), pad_of(rows), wide.dtype)
+    buckets = jnp.full((ndev * bucket_cap + 1, ar), pad_of(rows), rows.dtype)
     buckets = buckets.at[slot].set(jnp.where((t_sorted < ndev)[:, None],
-                                             wide, pad_of(rows)),
+                                             rows_sorted, pad_of(rows)),
                                    mode="drop")
-    return (buckets[:ndev * bucket_cap].reshape(ndev, bucket_cap, ar)
-            .astype(rows.dtype), jnp.sum(overflow))
+    return (buckets[:ndev * bucket_cap].reshape(ndev, bucket_cap, ar),
+            jnp.sum(overflow))
 
 
 def _exchange(rows, target, ndev, axis, bucket_cap, sort_cols=None):
@@ -223,13 +222,13 @@ def _merge_runs(blk, ndev, perm):
                     keys[j], keys[i],
                     side="right" if j < i else "left").astype(jnp.int32)
             ranks.append(rank)
-        out = jnp.full((n + 1, ar), pad_of(blk), ops._wide(blk).dtype)
+        out = jnp.full((n + 1, ar), pad_of(blk), blk.dtype)
         for i, r in enumerate(runs):
             pos = jnp.where(valids[i], ranks[i], n)    # PAD rows -> trash
-            out = out.at[pos].set(jnp.where(valids[i][:, None], ops._wide(r),
+            out = out.at[pos].set(jnp.where(valids[i][:, None], r,
                                             pad_of(blk)),
                                   mode="drop")
-        out = out[:n].astype(blk.dtype)
+        out = out[:n]
     if identity:
         return out
     inv = [0] * ar

@@ -196,13 +196,6 @@ def _kernel_width(data) -> bool:
     return data.dtype.itemsize <= 4
 
 
-def _wide(x):
-    """int32 view of narrower integer data (a no-op at 32 bits and up).
-    Sort keys and scattered rows go through it: on a TPU v5e an int16
-    store lost facts through these ops, which it does not at 32 bits."""
-    return x.astype(jnp.int32) if x.dtype.itemsize < 4 else x
-
-
 # ===========================================================================
 # traceable cores — pure jnp, shape-stable, no host interaction.  Safe to
 # call inside jit / while_loop / shard_map; static args (column indices,
@@ -220,7 +213,7 @@ def lexsort_core(data, pallas: bool | None = None):
     key = scalar_key(data)
     if key is not None:
         return data[jnp.argsort(key)]
-    keys = tuple(_wide(data[:, c]) for c in reversed(range(ar)))
+    keys = tuple(data[:, c] for c in reversed(range(ar)))
     return data[jnp.lexsort(keys)]
 
 
@@ -235,7 +228,7 @@ def keysort_core(data, key_col: int, pallas: bool | None = None):
         _, perm = K.sort_with_payload(data[:, key_col], vals,
                                       tile=min(1024, cap))
         return data[perm]
-    return data[jnp.argsort(_wide(data[:, key_col]))]
+    return data[jnp.argsort(data[:, key_col])]
 
 
 def dedup_mask_core(sorted_data, pallas: bool | None = None):
@@ -270,10 +263,9 @@ def compact_core(data, mask, out_cap: int):
     ``sum(mask) > out_cap``."""
     pos = jnp.cumsum(mask) - 1
     idx = jnp.where(mask, pos, out_cap)
-    rows = _wide(data)
-    out = jnp.full((out_cap + 1, data.shape[1]), pad_of(data), rows.dtype)
-    out = out.at[idx].set(rows, mode="drop")
-    return out[:out_cap].astype(data.dtype)
+    out = jnp.full((out_cap + 1, data.shape[1]), pad_of(data), data.dtype)
+    out = out.at[idx].set(data, mode="drop")
+    return out[:out_cap]
 
 
 def project_core(data, cols):
@@ -286,8 +278,8 @@ def project_core(data, cols):
 def join_count_core(ldata, rdata_sorted, lkey: int, rkey: int):
     """Count pass of the sort-merge join: per-left-row match ranges in the
     right block (sorted by ``rkey``).  Returns (total, per, cum, lo)."""
-    lk = _wide(ldata[:, lkey])
-    rk = _wide(rdata_sorted[:, rkey])
+    lk = ldata[:, lkey]
+    rk = rdata_sorted[:, rkey]
     lo = jnp.searchsorted(rk, lk, side="left")
     hi = jnp.searchsorted(rk, lk, side="right")
     per = jnp.where(lk != pad_of(ldata), hi - lo, 0)
@@ -337,11 +329,11 @@ def _range_narrow(col, key, lo, hi):
 
 
 def scalar_key(rows):
-    """The order-preserving scalar key of arity-1 rows (their one column,
-    as int32), or None: wider rows sort by all their columns and search
-    column by column."""
+    """The order-preserving scalar key of arity-1 rows (their one column),
+    or None: wider rows sort by all their columns and search column by
+    column."""
     if rows.shape[1] == 1:
-        return _wide(rows[:, 0])
+        return rows[:, 0]
     return None
 
 
@@ -439,10 +431,10 @@ def merge_core(A, B, na, nb):
     cnt = jnp.cumsum(h)[:out_cap]            # #{valid B rows lex< A[j]}
     pos_a = jnp.where(ia < na, ia + cnt, out_cap)
     pos_b = jnp.where(valid_b, ib + p, out_cap)
-    out = jnp.full((out_cap, ar), pad_of(A), _wide(A).dtype)
-    out = out.at[pos_a].set(_wide(A), mode="drop")
-    out = out.at[pos_b].set(_wide(B), mode="drop")
-    return out.astype(A.dtype)
+    out = jnp.full((out_cap, ar), pad_of(A), A.dtype)
+    out = out.at[pos_a].set(A, mode="drop")
+    out = out.at[pos_b].set(B, mode="drop")
+    return out
 
 
 # ===========================================================================

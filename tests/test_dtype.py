@@ -104,6 +104,21 @@ def test_member_mask_pack_vs_binary_search_fallback():
 
 
 @pytest.mark.parametrize("dtype", [np.int16, np.int32])
+def test_narrow_ops_check_matches_numpy(dtype):
+    """Every row core a narrow store runs (and the int16 pair key the
+    chip-side op check probes) agrees with numpy on the default backend."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(__file__), "..", "scripts",
+                        "narrow_ops_check.py")
+    spec = importlib.util.spec_from_file_location("narrow_ops_check", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    res = mod.check_ops(np.dtype(dtype), n=512, seed=3)
+    assert len(res) == (11 if dtype == np.int16 else 7)
+    assert all(res.values()), res
+
+
+@pytest.mark.parametrize("dtype", [np.int16, np.int32])
 @pytest.mark.parametrize("n", [0, 1, 3, 7])
 def test_dedup_edge_shapes(dtype, n):
     """Empty and non-pow2 row counts through the dtype-generic cores."""
