@@ -40,6 +40,8 @@ import os
 import sys
 import time
 
+from bench.harness import Compiles
+
 # Cold runs are compile-bound (an XLA TPU sort of 2^16 rows or more takes
 # 20-30 s to compile), so the sizes are those whose phases, measured cold
 # on one v5e chip, add up to well under 20 minutes: see PERF.md.
@@ -58,19 +60,6 @@ def check(cond, what):
     if not cond:
         raise CheckFailed(what)
     print(f"  ok: {what}", flush=True)
-
-
-class Compiles:
-    """Counts backend compiles and their seconds through jax.monitoring."""
-
-    def __init__(self, jax):
-        self.n, self.secs = 0, 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, name, secs, **_):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.n += 1
-            self.secs += secs
 
 
 def phase(name, compiles, fn):

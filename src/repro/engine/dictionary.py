@@ -44,6 +44,7 @@ from __future__ import annotations
 from typing import Dict, Hashable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.terms import Null
 from repro.engine.relation import id_range, store_dtype
@@ -154,7 +155,12 @@ class Dictionary:
         array of the dictionary's dtype.  One ``np.unique`` over the flat
         terms; per-distinct-term work only (and pure numpy for integer
         input).  Raises ``OverflowError`` before returning ids if interning
-        would leave the dtype's id range."""
+        would leave the dtype's id range.  Runs inside a ``tg.encode`` host
+        span."""
+        with TraceAnnotation("tg.encode"):
+            return self._encode_columns(rows)
+
+    def _encode_columns(self, rows) -> np.ndarray:
         rows = np.asarray(rows)
         if rows.ndim == 1:
             rows = rows.reshape(-1, 1)

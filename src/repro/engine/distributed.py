@@ -61,6 +61,12 @@ fixpoint-phase capacity retries and tail folds surface as extra
 Pallas routing is pinned off here: the kernels are not shard_map-
 transformable in interpret mode.
 
+Tracing: the programs are jitted as ``tg_dist_round`` and
+``tg_dist_fixpoint``; ``_exchange`` runs under the ``tg.exchange`` scope and
+``_merge_runs`` under ``tg.merge``; the host steps are the fused executor's
+spans (``tg.round``, ``tg.fixpoint``, ``tg.fold``) with ``tg.pull`` at site
+``dist``.
+
 Entry points: ``materialize(kb, mode="tg", backend="dist")`` (or
 ``REPRO_DIST=1``) routes through :func:`materialize_distributed`, falling
 back to the fused / two-phase executors for programs outside the fragment;
@@ -74,6 +80,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.engine import ops, recovery
@@ -163,6 +170,7 @@ def _route_to_buckets(rows, target, ndev, bucket_cap, sort_cols=None):
             jnp.sum(overflow))
 
 
+@jax.named_scope("tg.exchange")
 def _exchange(rows, target, ndev, axis, bucket_cap, sort_cols=None):
     """Fixed-capacity bucket exchange: rows (cap, ar) with target shard ids;
     rows routed via all_to_all; returns ((ndev*bucket_cap, ar) local rows,
@@ -180,6 +188,7 @@ def _exchange(rows, target, ndev, axis, bucket_cap, sort_cols=None):
 _MERGE_MAX_WAYS = 4      # ndev**2 pairwise rank probes beat a sort up to here
 
 
+@jax.named_scope("tg.merge")
 def _merge_runs(blk, ndev, perm):
     """Merge the ``ndev`` per-source sorted runs of an exchanged block into
     one front-packed block lexsorted in ``perm`` column order (``perm`` is
@@ -316,7 +325,7 @@ def _build_dist_round(mesh, axis, ndev, preds, caps, active, delta_in,
     delta_caps = {p: caps.delta_cap(p) for p in derived}
     bucket_caps = {k: caps.bucket_cap(k) for k in _bucket_keys(ovf_labels)}
 
-    def body(store_datas, store_counts, delta_datas):
+    def tg_dist_round(store_datas, store_counts, delta_datas):
         stores = dict(zip(preds, store_datas))
         counts = {p: c[0] for p, c in zip(preds, store_counts)}
         deltas = dict(zip(delta_in, delta_datas))
@@ -380,7 +389,7 @@ def _build_dist_round(mesh, axis, ndev, preds, caps, active, delta_in,
                  tuple(P(axis) for _ in derived),
                  tuple(P() for _ in derived),
                  P(), P())
-    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+    fn = jax.jit(jax.shard_map(tg_dist_round, mesh=mesh, in_specs=in_specs,
                                out_specs=out_specs))
     return fn, ovf_labels, derived
 
@@ -565,7 +574,7 @@ def _build_dist_fixpoint(mesh, axis, ndev, s_preds, o_preds, caps, active,
             data = ops.compact_core(data, mask, data.shape[0])
         return data
 
-    def fn(s_datas, d_datas, o_datas, rounds0):
+    def tg_dist_fixpoint(s_datas, d_datas, o_datas, rounds0):
         base = dict(zip(s_preds, s_datas))
         others = dict(zip(o_preds, o_datas))
         deltas0 = dict(zip(s_preds, d_datas))
@@ -760,8 +769,8 @@ def _build_dist_fixpoint(mesh, axis, ndev, s_preds, o_preds, caps, active,
                  tuple(P(axis, None) for _ in s_preds),
                  tuple(P(axis) for _ in s_preds),
                  P(), P(), P(), P())
-    return (jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs)),
+    return (jax.jit(jax.shard_map(tg_dist_fixpoint, mesh=mesh,
+                                  in_specs=in_specs, out_specs=out_specs)),
             ovf_labels)
 
 
@@ -949,20 +958,22 @@ def materialize_distributed(kb, mode: str = "tg", max_rounds: int = 10_000,
     def run_round(active, delta_preds, is_ext=False):
         prefilter = use_prefilter and not is_ext   # no Def. 23 in round 1
         while True:
-            sig = _dist_signature(mesh, axis, ndev, preds, caps, active,
-                                  delta_preds, prefilter)
-            fn, ovf_labels, derived = _cached_program(
-                sig, lambda: _build_dist_round(mesh, axis, ndev, preds, caps,
-                                               active, delta_preds,
-                                               prefilter))
-            out = fn(tuple(skb.fit(p, caps.store[p]) for p in preds),
-                     tuple(jnp.asarray(skb.counts[p]) for p in preds),
-                     tuple(fit_delta(p) for p in delta_preds))
-            n_stores, n_counts, n_deltas, n_dcounts, fresh, trg, ovf = out
-            # ONE blocking pull per round attempt, independent of ndev:
-            # counts + fresh totals + triggers + the overflow vector
-            pulled = jax.device_get((n_counts, fresh, trg, ovf))
-            ops.HOST_SYNC_STATS.dist_pulls += 1
+            with TraceAnnotation("tg.round", round=st.rounds):
+                sig = _dist_signature(mesh, axis, ndev, preds, caps, active,
+                                      delta_preds, prefilter)
+                fn, ovf_labels, derived = _cached_program(
+                    sig, lambda: _build_dist_round(mesh, axis, ndev, preds,
+                                                   caps, active, delta_preds,
+                                                   prefilter))
+                out = fn(tuple(skb.fit(p, caps.store[p]) for p in preds),
+                         tuple(jnp.asarray(skb.counts[p]) for p in preds),
+                         tuple(fit_delta(p) for p in delta_preds))
+                n_stores, n_counts, n_deltas, n_dcounts, fresh, trg, ovf = out
+                # ONE blocking pull per round attempt, independent of ndev:
+                # counts + fresh totals + triggers + the overflow vector
+                with TraceAnnotation("tg.pull", site="dist"):
+                    pulled = jax.device_get((n_counts, fresh, trg, ovf))
+                ops.HOST_SYNC_STATS.dist_pulls += 1
             cnts, fresh, trg, ovf = pulled
             if not ovf.any():
                 budget.ok()
@@ -1037,26 +1048,28 @@ def materialize_distributed(kb, mode: str = "tg", max_rounds: int = 10_000,
         s_preds_, active = tail
         o_preds_ = tuple(p for p in preds if p not in s_preds_)
         while True:
-            sig = _dist_fix_signature(mesh, axis, ndev, s_preds_, o_preds_,
-                                      caps, active, use_prefilter,
-                                      max_rounds)
-            fn, ovf_labels = _cached_program(
-                sig, lambda: _build_dist_fixpoint(
-                    mesh, axis, ndev, s_preds_, o_preds_, caps, active,
-                    use_prefilter, max_rounds))
-            out = fn(tuple(skb.fit(p, caps.store[p]) for p in s_preds_),
-                     tuple(fit_delta_fix(p) for p in s_preds_),
-                     tuple(skb.fit(p, caps.store[p]) for p in o_preds_),
-                     jnp.int32(st.rounds))
-            w_datas, w_counts, d_datas, d_counts, rounds, trg, drv, ovf = \
-                out
-            # ONE blocking pull per fixpoint-program exit: tail + delta
-            # counts, the loop's round/trigger/derived totals, and the
-            # overflow vector
-            pulled = jax.device_get((w_counts, d_counts, rounds, trg, drv,
-                                     ovf))
-            ops.HOST_SYNC_STATS.dist_pulls += 1
-            ops.HOST_SYNC_STATS.dist_fixpoint_pulls += 1
+            with TraceAnnotation("tg.fixpoint", round=st.rounds):
+                sig = _dist_fix_signature(mesh, axis, ndev, s_preds_,
+                                          o_preds_, caps, active,
+                                          use_prefilter, max_rounds)
+                fn, ovf_labels = _cached_program(
+                    sig, lambda: _build_dist_fixpoint(
+                        mesh, axis, ndev, s_preds_, o_preds_, caps, active,
+                        use_prefilter, max_rounds))
+                out = fn(tuple(skb.fit(p, caps.store[p]) for p in s_preds_),
+                         tuple(fit_delta_fix(p) for p in s_preds_),
+                         tuple(skb.fit(p, caps.store[p]) for p in o_preds_),
+                         jnp.int32(st.rounds))
+                w_datas, w_counts, d_datas, d_counts, rounds, trg, drv, \
+                    ovf = out
+                # ONE blocking pull per fixpoint-program exit: tail + delta
+                # counts, the loop's round/trigger/derived totals, and the
+                # overflow vector
+                with TraceAnnotation("tg.pull", site="dist"):
+                    pulled = jax.device_get((w_counts, d_counts, rounds, trg,
+                                             drv, ovf))
+                ops.HOST_SYNC_STATS.dist_pulls += 1
+                ops.HOST_SYNC_STATS.dist_fixpoint_pulls += 1
             wcnts, dcnts, rounds, trg, drv, ovf = pulled
             ops.HOST_SYNC_STATS.dist_fixpoint_iters += \
                 int(rounds) - st.rounds
@@ -1066,7 +1079,8 @@ def materialize_distributed(kb, mode: str = "tg", max_rounds: int = 10_000,
             st.derived += int(drv)
             deltas = {p: d for p, d, c in zip(s_preds_, d_datas, dcnts)
                       if int(np.asarray(c).sum())}
-            fold_tails(s_preds_, w_datas, wcnts)
+            with TraceAnnotation("tg.fold"):
+                fold_tails(s_preds_, w_datas, wcnts)
             if st.rounds > prev_rounds:
                 budget.ok()     # the loop advanced: real progress
                 progressed[0] = True

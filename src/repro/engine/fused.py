@@ -33,12 +33,20 @@ never recomputes from scratch.
 
 Eligibility: Datalog rules (no existentials) with connected bodies.
 ``materialize()`` falls back to the two-phase path for anything else.
+
+Tracing: the programs are jitted as ``tg_round`` and ``tg_fixpoint``, the
+names the device trace's ``XLA Modules`` line shows.  On the host, each
+round attempt is one ``tg.round`` span and each fixpoint-program entry one
+``tg.fixpoint`` span (``jax.profiler.TraceAnnotation``), from argument
+preparation to the return of the ``tg.pull`` inside it; the fold of tails
+into the stores after a fixpoint exit is ``tg.fold``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.engine import ops, recovery
 from repro.engine.plan import (_absorb_traced, _cached_program, _Caps,
@@ -88,7 +96,7 @@ def _build_round(preds, caps, active, delta_in, use_prefilter, pallas):
                  for plan, _ in active}
     delta_caps = {p: caps.delta_cap(p) for p in derived}
 
-    def fn(store_datas, store_counts, delta_datas):
+    def tg_round(store_datas, store_counts, delta_datas):
         stores = dict(zip(preds, store_datas))
         counts = dict(zip(preds, store_counts))
         deltas = dict(zip(delta_in, delta_datas))
@@ -122,7 +130,7 @@ def _build_round(preds, caps, active, delta_in, use_prefilter, pallas):
                 tuple(counts[p] for p in preds),
                 tuple(out_deltas), tuple(out_dcounts), triggers, ovf_vec)
 
-    return jax.jit(fn), ovf_labels, derived
+    return jax.jit(tg_round), ovf_labels, derived
 
 
 # ---------------------------------------------------------------------------
@@ -168,7 +176,8 @@ def _build_fixpoint(s_preds, o_preds, caps, active, use_prefilter, pallas,
                  for plan, _ in active}
     delta_caps = {p: caps.delta_cap(p) for p in s_preds}
 
-    def fn(s_base, w_datas, w_counts, d_datas, d_counts, o_datas, rounds):
+    def tg_fixpoint(s_base, w_datas, w_counts, d_datas, d_counts, o_datas,
+                    rounds):
         base = dict(zip(s_preds, s_base))
         others = dict(zip(o_preds, o_datas))
 
@@ -251,7 +260,7 @@ def _build_fixpoint(s_preds, o_preds, caps, active, use_prefilter, pallas,
 
     # loop-state buffers are donated on accelerator backends (exits return
     # the last-good state, so the donated inputs are never needed again)
-    return (jax.jit(fn, donate_argnums=(1, 3) if donate else ()),
+    return (jax.jit(tg_fixpoint, donate_argnums=(1, 3) if donate else ()),
             ovf_labels)
 
 
@@ -348,18 +357,22 @@ def materialize_fused(kb, mode: str = "tg", max_rounds: int = 10_000,
         nonlocal stores, counts
         prefilter = use_prefilter and not is_ext   # no Def. 23 in round 1
         while True:
-            sig = _round_signature(preds, caps, active, delta_preds,
-                                   prefilter, pallas)
-            fn, ovf_labels, derived = _cached_program(
-                sig, lambda: _build_round(preds, caps, active, delta_preds,
-                                          prefilter, pallas))
-            out = fn(tuple(stores[p] for p in preds),
-                     tuple(jnp.int32(counts[p]) for p in preds),
-                     tuple(ops.fit_rows(deltas[p][0], caps.delta_cap(p))
-                           for p in delta_preds))
-            n_stores, n_counts, n_deltas, n_dcounts, trg, ovf_vec = out
-            pulled = jax.device_get((n_counts, n_dcounts, trg, ovf_vec))
-            ops.HOST_SYNC_STATS.fused_pulls += 1
+            with TraceAnnotation("tg.round", round=st.rounds):
+                sig = _round_signature(preds, caps, active, delta_preds,
+                                       prefilter, pallas)
+                fn, ovf_labels, derived = _cached_program(
+                    sig, lambda: _build_round(preds, caps, active,
+                                              delta_preds, prefilter,
+                                              pallas))
+                out = fn(tuple(stores[p] for p in preds),
+                         tuple(jnp.int32(counts[p]) for p in preds),
+                         tuple(ops.fit_rows(deltas[p][0], caps.delta_cap(p))
+                               for p in delta_preds))
+                n_stores, n_counts, n_deltas, n_dcounts, trg, ovf_vec = out
+                with TraceAnnotation("tg.pull", site="fused"):
+                    pulled = jax.device_get((n_counts, n_dcounts, trg,
+                                             ovf_vec))
+                ops.HOST_SYNC_STATS.fused_pulls += 1
             cnts, dcnts, trg, ovf = pulled
             if not ovf.any():
                 budget.ok()
@@ -412,38 +425,44 @@ def materialize_fused(kb, mode: str = "tg", max_rounds: int = 10_000,
                 o_preds = tuple(p for p in preds if p not in s_preds)
                 w = {p: None for p in s_preds}  # sorted tails (data, count)
                 while True:
-                    sig = _fix_signature(s_preds, o_preds, caps, active,
-                                         use_prefilter, pallas, max_rounds,
-                                         donate)
-                    fn, ovf_labels = _cached_program(
-                        sig, lambda: _build_fixpoint(
-                            s_preds, o_preds, caps, active, use_prefilter,
-                            pallas, max_rounds, donate))
-                    out = fn(
-                        tuple(stores[p] for p in s_preds),
-                        tuple(jnp.array(ops.fit_rows(w[p][0],
-                                                     caps.tail_cap(p)))
-                              if w[p] else
-                              jnp.full((caps.tail_cap(p), kb.arities[p]),
-                                       kb.rels[p].pad, kb.rels[p].dtype)
-                              for p in s_preds),
-                        tuple(jnp.int32(w[p][1] if w[p] else 0)
-                              for p in s_preds),
-                        tuple(jnp.array(ops.fit_rows(deltas[p][0],
-                                                     caps.delta_cap(p)))
-                              if p in deltas else
-                              jnp.full((caps.delta_cap(p), kb.arities[p]),
-                                       kb.rels[p].pad, kb.rels[p].dtype)
-                              for p in s_preds),
-                        tuple(jnp.int32(deltas[p][1] if p in deltas else 0)
-                              for p in s_preds),
-                        tuple(stores[p] for p in o_preds),
-                        jnp.int32(st.rounds))
-                    w_datas, w_counts, d_datas, d_counts, rounds, trg, \
-                        drv, ovf_vec = out
-                    pulled = jax.device_get((w_counts, d_counts, rounds,
-                                             trg, drv, ovf_vec))
-                    ops.HOST_SYNC_STATS.fused_pulls += 1
+                    with TraceAnnotation("tg.fixpoint", round=st.rounds):
+                        sig = _fix_signature(s_preds, o_preds, caps,
+                                             active, use_prefilter, pallas,
+                                             max_rounds, donate)
+                        fn, ovf_labels = _cached_program(
+                            sig, lambda: _build_fixpoint(
+                                s_preds, o_preds, caps, active,
+                                use_prefilter, pallas, max_rounds, donate))
+                        out = fn(
+                            tuple(stores[p] for p in s_preds),
+                            tuple(jnp.array(ops.fit_rows(w[p][0],
+                                                         caps.tail_cap(p)))
+                                  if w[p] else
+                                  jnp.full((caps.tail_cap(p),
+                                            kb.arities[p]),
+                                           kb.rels[p].pad, kb.rels[p].dtype)
+                                  for p in s_preds),
+                            tuple(jnp.int32(w[p][1] if w[p] else 0)
+                                  for p in s_preds),
+                            tuple(jnp.array(ops.fit_rows(deltas[p][0],
+                                                         caps.delta_cap(p)))
+                                  if p in deltas else
+                                  jnp.full((caps.delta_cap(p),
+                                            kb.arities[p]),
+                                           kb.rels[p].pad, kb.rels[p].dtype)
+                                  for p in s_preds),
+                            tuple(jnp.int32(deltas[p][1] if p in deltas
+                                            else 0)
+                                  for p in s_preds),
+                            tuple(stores[p] for p in o_preds),
+                            jnp.int32(st.rounds))
+                        w_datas, w_counts, d_datas, d_counts, rounds, trg, \
+                            drv, ovf_vec = out
+                        with TraceAnnotation("tg.pull", site="fused"):
+                            pulled = jax.device_get((w_counts, d_counts,
+                                                     rounds, trg, drv,
+                                                     ovf_vec))
+                        ops.HOST_SYNC_STATS.fused_pulls += 1
                     wcnts, dcnts, rounds, trg, drv, ovf = pulled
                     prev_rounds = st.rounds
                     st.rounds = int(rounds)
@@ -454,18 +473,19 @@ def materialize_fused(kb, mode: str = "tg", max_rounds: int = 10_000,
                     # fold tails into the stores (exits are rare: done, a
                     # full tail, or a capacity retry)
                     ar = kb.arities
-                    for p, d, c in zip(s_preds, w_datas, wcnts):
-                        w[p] = None
-                        if int(c):
-                            merged = ops.merge_union(
-                                Relation(stores[p], counts[p],
-                                         lex_order(ar[p])),
-                                Relation(d, int(c), lex_order(ar[p])))
-                            counts[p] = merged.count
-                            caps.store[p] = max(caps.store[p],
-                                                merged.capacity)
-                            stores[p] = ops.fit_rows(merged.data,
-                                                     caps.store[p])
+                    with TraceAnnotation("tg.fold"):
+                        for p, d, c in zip(s_preds, w_datas, wcnts):
+                            w[p] = None
+                            if int(c):
+                                merged = ops.merge_union(
+                                    Relation(stores[p], counts[p],
+                                             lex_order(ar[p])),
+                                    Relation(d, int(c), lex_order(ar[p])))
+                                counts[p] = merged.count
+                                caps.store[p] = max(caps.store[p],
+                                                    merged.capacity)
+                                stores[p] = ops.fit_rows(merged.data,
+                                                         caps.store[p])
                     if st.rounds > prev_rounds:
                         budget.ok()     # the loop advanced: real progress
                         progressed = True

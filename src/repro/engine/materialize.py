@@ -37,6 +37,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.terms import Atom, Program, Rule, Var, is_var
 from repro.engine import ops, recovery
@@ -65,14 +66,16 @@ class EngineKB:
         self.base: Dict[str, Relation] = {}
         for p, ar in self.arities.items():
             if p in rows:
-                rel = Relation.from_numpy(self._encode_block(rows[p], ar))
-                # set semantics hold on every path: duplicate base facts are
-                # collapsed regardless of REPRO_SORTED_STORE, so fact counts
-                # and trigger stats agree across flag settings.  (With the
-                # sorted store this doubles as the store invariant: every
-                # store relation is lexsorted, so per-round dedup/antijoin
-                # skip their sort pass and unions become incremental merges.)
-                rel = ops.dedup(rel)
+                with TraceAnnotation("tg.ingest", pred=p):
+                    rel = Relation.from_numpy(self._encode_block(rows[p], ar))
+                    # set semantics hold on every path: duplicate base facts
+                    # are collapsed regardless of REPRO_SORTED_STORE, so fact
+                    # counts and trigger stats agree across flag settings.
+                    # (With the sorted store this doubles as the store
+                    # invariant: every store relation is lexsorted, so
+                    # per-round dedup/antijoin skip their sort pass and
+                    # unions become incremental merges.)
+                    rel = ops.dedup(rel)
                 self.rels[p] = rel
             else:
                 self.rels[p] = Relation.empty(max(ar, 1),
@@ -87,12 +90,13 @@ class EngineKB:
         n = len(fact_args)
         if n == 0 or ar == 0:
             return np.zeros((n, ar), self.dict.id_dtype)
-        try:
-            return self.dict.encode_columns(
-                np.array(fact_args, dtype=object))
-        except TypeError:
-            enc = [self.dict.encode_many(args) for args in fact_args]
-            return np.asarray(enc, self.dict.id_dtype).reshape(n, ar)
+        with TraceAnnotation("tg.encode"):
+            try:
+                return self.dict.encode_columns(
+                    np.array(fact_args, dtype=object))
+            except TypeError:
+                enc = [self.dict.encode_many(args) for args in fact_args]
+                return np.asarray(enc, self.dict.id_dtype).reshape(n, ar)
 
     # -- streamed ingest ----------------------------------------------------
     def ingest_rows(self, pred: str, rows: np.ndarray) -> None:
@@ -108,6 +112,10 @@ class EngineKB:
         marked first and rolled back if anything in the chunk fails to
         encode or merge — a malformed chunk raises and leaves both the
         dictionary and the store exactly as they were."""
+        with TraceAnnotation("tg.ingest", pred=pred):
+            self._ingest_chunk(pred, rows)
+
+    def _ingest_chunk(self, pred: str, rows) -> None:
         rows = np.asarray(rows) if not isinstance(rows, np.ndarray) else rows
         if rows.ndim == 1:
             rows = rows.reshape(-1, 1)
@@ -332,7 +340,18 @@ def materialize(kb: EngineKB, mode: str = "tg", max_rounds: int = 10_000,
     (sharded shard_map executor over every local device) | "local".  The
     distributed backend covers the plannable fragment of ``tg``/``tg_noopt``
     (no existentials, connected bodies); anything else falls back to the
-    fused / two-phase executors below."""
+    fused / two-phase executors below.
+
+    The call runs inside a ``tg.materialize`` host span whose ``executor``
+    names the executor that finished it."""
+    with TraceAnnotation("tg.materialize") as span:
+        st = _materialize(kb, mode, max_rounds, tg_eg, cleaning, backend)
+        span.set_metadata(executor="dist" if st.extra.get("dist") else
+                          "fused" if st.extra.get("fused") else "two-phase")
+    return st
+
+
+def _materialize(kb, mode, max_rounds, tg_eg, cleaning, backend):
     if mode == "tg_linear":
         return _materialize_tg_linear(kb, tg_eg, cleaning)
     assert mode in ("seminaive", "tg", "tg_noopt")

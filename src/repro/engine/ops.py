@@ -47,6 +47,16 @@ compiled on TPU).  The jnp implementations here are the reference path and
 the default.  Multi-column lexsorts, membership probes and the merge-union
 binary searches stay on the jnp path in both modes.
 
+Tracing
+-------
+Each core runs under a ``jax.named_scope`` (``tg.sort``, ``tg.join``,
+``tg.probe``, ``tg.merge``, ``tg.compact``), so every device op it emits
+carries the scope in its ``op_name`` metadata; where scopes nest, the
+outermost one names the op (a probe inside ``merge_core`` is merge work).
+Every blocking count pull runs inside a ``tg.pull`` host span
+(``jax.profiler.TraceAnnotation``) beside its ``HOST_SYNC_STATS``
+increment.  Neither costs anything unless a profiler runs.
+
 Env-flag matrix
 ---------------
 =================== ======= ====================================================
@@ -66,6 +76,7 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.engine.relation import (PAD, Relation, lex_order, next_pow2,
                                    pad_of)
@@ -116,8 +127,13 @@ def _kernels():
 
 @dataclass
 class SortStats:
-    """Counts of sort passes performed / avoided (the paper's redundant-work
-    argument, applied to the engine's own hot path)."""
+    """Python-level calls on the two-phase host path: sorts run by
+    ``lexsort_rows`` / ``sort_by``, sorts they skipped through a
+    ``sorted_by`` marker, and ``merge_union`` / ``merge_diff`` merges.  Read
+    by ``tests/test_sorted_store.py`` and ``benchmarks/``.  It is not a count
+    of the sorts in the fused programs, whose cores run on the device unseen
+    by these counters: there the device trace's time under the ``tg.sort``
+    scope is the measure."""
     lexsort: int = 0       # full row lexsorts executed
     key_sort: int = 0      # single-key sorts executed (sm_join inputs)
     merges: int = 0        # incremental merge-unions executed
@@ -201,6 +217,7 @@ def _kernel_width(data) -> bool:
 # call inside jit / while_loop / shard_map; static args (column indices,
 # capacities, pallas routing) must be python values at trace time.
 # ===========================================================================
+@jax.named_scope("tg.sort")
 def lexsort_core(data, pallas: bool | None = None):
     """Full-row lexicographic sort of a padded (cap, ar) block (PAD rows
     sort last).  Single-column blocks route through the Pallas sort kernel
@@ -217,6 +234,7 @@ def lexsort_core(data, pallas: bool | None = None):
     return data[jnp.lexsort(keys)]
 
 
+@jax.named_scope("tg.sort")
 def keysort_core(data, key_col: int, pallas: bool | None = None):
     """Sort rows of a padded block by one key column."""
     cap = data.shape[0]
@@ -231,6 +249,7 @@ def keysort_core(data, key_col: int, pallas: bool | None = None):
     return data[jnp.argsort(data[:, key_col])]
 
 
+@jax.named_scope("tg.compact")
 def dedup_mask_core(sorted_data, pallas: bool | None = None):
     """First-occurrence mask over lexsorted rows (PAD rows excluded)."""
     if pallas is None:
@@ -245,6 +264,7 @@ def dedup_mask_core(sorted_data, pallas: bool | None = None):
     return jnp.logical_and(neq, valid)
 
 
+@jax.named_scope("tg.compact")
 def filter_mask_core(data, eq_pairs=(), const_pairs=()):
     """Row-selection mask: valid rows meeting column-equality (repeated
     vars) and column-constant constraints."""
@@ -256,6 +276,7 @@ def filter_mask_core(data, eq_pairs=(), const_pairs=()):
     return valid
 
 
+@jax.named_scope("tg.compact")
 def compact_core(data, mask, out_cap: int):
     """Scatter masked rows to the front of a fresh (out_cap, ar) PAD block,
     preserving their relative order (so sortedness survives compaction).
@@ -268,6 +289,7 @@ def compact_core(data, mask, out_cap: int):
     return out[:out_cap]
 
 
+@jax.named_scope("tg.compact")
 def project_core(data, cols):
     """Column gather; invalid (PAD) rows stay fully PAD."""
     valid = data[:, 0] != pad_of(data)
@@ -275,6 +297,7 @@ def project_core(data, cols):
     return jnp.where(valid[:, None], out, pad_of(data))
 
 
+@jax.named_scope("tg.join")
 def join_count_core(ldata, rdata_sorted, lkey: int, rkey: int):
     """Count pass of the sort-merge join: per-left-row match ranges in the
     right block (sorted by ``rkey``).  Returns (total, per, cum, lo)."""
@@ -287,6 +310,7 @@ def join_count_core(ldata, rdata_sorted, lkey: int, rkey: int):
     return jnp.sum(per), per, cum, lo
 
 
+@jax.named_scope("tg.join")
 def join_gather_core(ldata, rdata, per, cum, lo, total, out_cap: int):
     """Materialize pass: emit [l cols..., r cols...] rows into a
     (out_cap, lar+rar) block.  Rows past ``out_cap`` are dropped (overflow
@@ -337,6 +361,7 @@ def scalar_key(rows):
     return None
 
 
+@jax.named_scope("tg.probe")
 def lex_range_core(hay_sorted, probe):
     """Per-probe-row [lo, hi) occurrence range in a lexsorted haystack:
     per-column range narrowing; when a column value is absent the range
@@ -370,6 +395,7 @@ def _lex_searchsorted_right(hay, probe):
     return lex_range_core(hay, probe)[1]
 
 
+@jax.named_scope("tg.probe")
 def member_mask_core(probe_rows, hay_sorted):
     """Row membership of each probe row in a lexsorted haystack (PAD probe
     rows report non-member: PAD columns never match valid haystack rows and
@@ -387,6 +413,7 @@ def member_mask_core(probe_rows, hay_sorted):
     return jnp.logical_and(hi > lo, valid)
 
 
+@jax.named_scope("tg.probe")
 def anti_keep_core(data, hay_sorted, cols):
     """Keep-mask for the antijoin: valid rows of ``data`` whose ``cols``
     tuple does NOT occur in the lexsorted haystack."""
@@ -395,6 +422,7 @@ def anti_keep_core(data, hay_sorted, cols):
     return jnp.logical_and(valid, jnp.logical_not(found))
 
 
+@jax.named_scope("tg.merge")
 def merge_diff_core(A, B_sorted, out_cap: int):
     """Sorted set-difference: rows of block A (lexsorted) minus rows of
     lexsorted block B, compacted into a fresh (out_cap, ar) PAD block.
@@ -407,6 +435,7 @@ def merge_diff_core(A, B_sorted, out_cap: int):
     return compact_core(A, keep, out_cap), n
 
 
+@jax.named_scope("tg.merge")
 def merge_core(A, B, na, nb):
     """Merge sorted block B (bcap rows, nb valid) into sorted block A
     (out_cap rows, na valid).  Duplicate rows may appear within and across
@@ -446,9 +475,9 @@ def merge_core(A, B, na, nb):
 @lru_cache(maxsize=None)
 def _lexsort_fn(cap, ar, pallas):
     @jax.jit
-    def f(data):
+    def tg_lexsort(data):
         return lexsort_core(data, pallas=pallas)
-    return f
+    return tg_lexsort
 
 
 def lexsort_rows(rel: Relation) -> Relation:
@@ -464,18 +493,18 @@ def lexsort_rows(rel: Relation) -> Relation:
 @lru_cache(maxsize=None)
 def _dedup_count_fn(cap, ar, pallas):
     @jax.jit
-    def f(sorted_data):
+    def tg_dedup_count(sorted_data):
         mask = dedup_mask_core(sorted_data, pallas=pallas)
         return jnp.sum(mask), mask
-    return f
+    return tg_dedup_count
 
 
 @lru_cache(maxsize=None)
 def _compact_fn(cap, ar, out_cap):
     @jax.jit
-    def f(data, mask):
+    def tg_compact(data, mask):
         return compact_core(data, mask, out_cap)
-    return f
+    return tg_compact
 
 
 def dedup(rel: Relation) -> Relation:
@@ -485,7 +514,8 @@ def dedup(rel: Relation) -> Relation:
         return Relation.empty(rel.arity, dtype=rel.dtype)
     s = lexsort_rows(rel)
     n, mask = _dedup_count_fn(s.capacity, s.arity, use_pallas())(s.data)
-    n = int(n)
+    with TraceAnnotation("tg.pull", site="count"):
+        n = int(n)
     HOST_SYNC_STATS.count_pulls += 1
     cap = next_pow2(n)
     out = _compact_fn(s.capacity, s.arity, cap)(s.data, mask)
@@ -498,10 +528,10 @@ def dedup(rel: Relation) -> Relation:
 @lru_cache(maxsize=None)
 def _filter_count_fn(cap, ar, eq_pairs, const_pairs):
     @jax.jit
-    def f(data):
+    def tg_filter_count(data):
         valid = filter_mask_core(data, eq_pairs, const_pairs)
         return jnp.sum(valid), valid
-    return f
+    return tg_filter_count
 
 
 def filter_rows(rel: Relation, eq_pairs=(), const_pairs=()) -> Relation:
@@ -511,7 +541,8 @@ def filter_rows(rel: Relation, eq_pairs=(), const_pairs=()) -> Relation:
         return rel
     n, mask = _filter_count_fn(rel.capacity, rel.arity, tuple(eq_pairs),
                                tuple(const_pairs))(rel.data)
-    n = int(n)
+    with TraceAnnotation("tg.pull", site="count"):
+        n = int(n)
     HOST_SYNC_STATS.count_pulls += 1
     cap = next_pow2(n)
     out = _compact_fn(rel.capacity, rel.arity, cap)(rel.data, mask)
@@ -521,9 +552,9 @@ def filter_rows(rel: Relation, eq_pairs=(), const_pairs=()) -> Relation:
 @lru_cache(maxsize=None)
 def _project_fn(cap, ar, cols):
     @jax.jit
-    def f(data):
+    def tg_project(data):
         return project_core(data, cols)
-    return f
+    return tg_project
 
 
 def project(rel: Relation, cols) -> Relation:
@@ -540,9 +571,9 @@ def project(rel: Relation, cols) -> Relation:
 @lru_cache(maxsize=None)
 def _sortby_fn(cap, ar, key_col, pallas):
     @jax.jit
-    def f(data):
+    def tg_keysort(data):
         return keysort_core(data, key_col, pallas=pallas)
-    return f
+    return tg_keysort
 
 
 def sort_by(rel: Relation, key_col: int) -> Relation:
@@ -561,17 +592,17 @@ def sort_by(rel: Relation, key_col: int) -> Relation:
 @lru_cache(maxsize=None)
 def _join_count_fn(lcap, lar, rcap, rar, lkey, rkey):
     @jax.jit
-    def f(l, r):
+    def tg_join_count(l, r):
         return join_count_core(l, r, lkey, rkey)
-    return f
+    return tg_join_count
 
 
 @lru_cache(maxsize=None)
 def _join_mat_fn(lcap, lar, rcap, rar, out_cap):
     @jax.jit
-    def f(l, r, per, cum, lo, total):
+    def tg_join_gather(l, r, per, cum, lo, total):
         return join_gather_core(l, r, per, cum, lo, total, out_cap)
-    return f
+    return tg_join_gather
 
 
 def sm_join(l: Relation, r: Relation, lkey: int, rkey: int):
@@ -584,7 +615,8 @@ def sm_join(l: Relation, r: Relation, lkey: int, rkey: int):
     rs = sort_by(r, rkey)
     total, per, cum, lo = _join_count_fn(
         l.capacity, l.arity, r.capacity, r.arity, lkey, rkey)(ls.data, rs.data)
-    total = int(total)
+    with TraceAnnotation("tg.pull", site="count"):
+        total = int(total)
     HOST_SYNC_STATS.count_pulls += 1
     if total == 0:
         return Relation.empty(l.arity + r.arity), 0
@@ -615,10 +647,10 @@ def cross(l: Relation, r: Relation):
 @lru_cache(maxsize=None)
 def _anti_count_fn(cap, ar, hcap, har, cols):
     @jax.jit
-    def f(data, hay_sorted):
+    def tg_anti_count(data, hay_sorted):
         keep = anti_keep_core(data, hay_sorted, cols)
         return jnp.sum(keep), keep
-    return f
+    return tg_anti_count
 
 
 def antijoin(rel: Relation, hay: Relation, cols=None) -> Relation:
@@ -635,7 +667,8 @@ def antijoin(rel: Relation, hay: Relation, cols=None) -> Relation:
     hs = lexsort_rows(hay)
     n, keep = _anti_count_fn(rel.capacity, rel.arity, hs.capacity,
                              hay.arity, cols)(rel.data, hs.data)
-    n = int(n)
+    with TraceAnnotation("tg.pull", site="count"):
+        n = int(n)
     HOST_SYNC_STATS.count_pulls += 1
     if n == rel.count:
         return rel
@@ -652,12 +685,12 @@ def antijoin(rel: Relation, hay: Relation, cols=None) -> Relation:
 @lru_cache(maxsize=None)
 def _semi_count_fn(cap, ar, hcap, har, cols):
     @jax.jit
-    def f(data, hay_sorted):
+    def tg_semi_count(data, hay_sorted):
         valid = data[:, 0] != pad_of(data)
         found = member_mask_core(project_core(data, cols), hay_sorted)
         keep = jnp.logical_and(valid, found)
         return jnp.sum(keep), keep
-    return f
+    return tg_semi_count
 
 
 def semijoin(rel: Relation, hay: Relation, cols=None) -> Relation:
@@ -671,7 +704,8 @@ def semijoin(rel: Relation, hay: Relation, cols=None) -> Relation:
     hs = lexsort_rows(hay)
     n, keep = _semi_count_fn(rel.capacity, rel.arity, hs.capacity,
                              hay.arity, cols)(rel.data, hs.data)
-    n = int(n)
+    with TraceAnnotation("tg.pull", site="count"):
+        n = int(n)
     HOST_SYNC_STATS.count_pulls += 1
     if n == rel.count:
         return rel
@@ -715,9 +749,9 @@ def fit_rows(data, out_cap):
 @lru_cache(maxsize=None)
 def _merge_fn(cap, bcap, ar):
     @jax.jit
-    def f(A, B, na, nb):
+    def tg_merge(A, B, na, nb):
         return merge_core(A, B, na, nb)
-    return f
+    return tg_merge
 
 
 def merge_union(a: Relation, b: Relation) -> Relation:
@@ -746,9 +780,9 @@ def merge_union(a: Relation, b: Relation) -> Relation:
 @lru_cache(maxsize=None)
 def _diff_fn(cap, hcap, ar, out_cap):
     @jax.jit
-    def f(A, B):
+    def tg_merge_diff(A, B):
         return merge_diff_core(A, B, out_cap)
-    return f
+    return tg_merge_diff
 
 
 def merge_diff(a: Relation, b: Relation) -> Relation:
@@ -768,7 +802,8 @@ def merge_diff(a: Relation, b: Relation) -> Relation:
     out_cap = a.capacity
     out, n = _diff_fn(a.capacity, b.capacity, a.arity, out_cap)(a.data,
                                                                 b.data)
-    n = int(n)
+    with TraceAnnotation("tg.pull", site="count"):
+        n = int(n)
     HOST_SYNC_STATS.count_pulls += 1
     SORT_STATS.merges += 1
     return Relation(out, n, lex_order(a.arity))

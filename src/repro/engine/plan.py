@@ -287,6 +287,7 @@ def _select_state(bad, old, new):
 # ---------------------------------------------------------------------------
 # traced pieces (built from the ops cores; no host interaction)
 # ---------------------------------------------------------------------------
+@jax.named_scope("tg.compact")
 def _project_head_core(data, spec):
     cols = []
     for kind, v in spec:

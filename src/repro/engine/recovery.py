@@ -58,6 +58,7 @@ import pickle
 import shutil
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.engine import faultinject
 from repro.engine.relation import Relation, lex_order
@@ -252,7 +253,8 @@ class EngineCheckpointer:
       runs the fault hooks, then honors a pending SIGTERM by exiting 143
       (the save above already made the state durable).  ``state_fn`` is
       lazy: full stores are only pulled to the host when a save actually
-      happens.
+      happens.  A save (the pull and the write) is one ``tg.checkpoint``
+      host span.
 
     Disabled (all methods cheap no-ops except the fault hooks) when
     ``REPRO_CKPT_DIR`` is unset or ``enabled=False`` (incremental delta
@@ -341,7 +343,8 @@ class EngineCheckpointer:
                 and st.rounds > self._last_saved
                 and (done or preempt
                      or st.rounds - self._last_saved >= self.every)):
-            self._save(st, state_fn(), caps, done=done)
+            with TraceAnnotation("tg.checkpoint"):
+                self._save(st, state_fn(), caps, done=done)
         self.faults.on_boundary(st.rounds)
         if preempt:
             raise SystemExit(143)
